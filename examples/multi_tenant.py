@@ -1,144 +1,55 @@
 """Multi-tenant TPU-pod serving — the paper's system LIVE on real sub-meshes.
 
-This is MIGM's end-to-end flow on actual (forced-host) JAX devices:
+This is MIGM's end-to-end flow on (forced-host) JAX devices, through
+:func:`repro.launch.tenants.run_pod`:
 
-  1. a 4x4 "pod" of 16 devices is managed by the buddy-slice
+  1. a 2x2 "pod" of 4 devices is managed by the buddy-slice
      PartitionStateMachine (the TPU adaptation of the A100 MIG FSM);
-  2. jobs (small transformer serving tasks of different sizes) arrive in a
-     queue; the scheduler sizes each via the static estimator, asks the
-     partition manager for a tight slice (Alg. 3 argmax-reachability), and
-     jits the job onto that slice's device mesh;
-  3. one job has a growing context; the MemoryAccountant + time-series
+  2. three tenants (small transformer serving tasks) each lease a tight
+     slice (Alg. 3 argmax-reachability), and their params and KV caches are
+     placed on that slice's devices;
+  3. one tenant has a growing context; the MemoryAccountant + time-series
      predictor watch its allocator stats and raise NeedsLargerPartition —
-     the scheduler performs the checkpointless early restart onto a bigger
-     slice (re-jit + device_put), exactly the paper's §2.3 flow.
+     the pod performs the checkpointless early restart onto a bigger slice
+     (re-jit + device_put, sharded over the slice), exactly the paper's §2.3
+     flow.
 
     PYTHONPATH=src python examples/multi_tenant.py
+
+On a four-chip TPU host the same function runs at full width:
+``python chip_smoke.py --chips 4``.
 """
 
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=16")
-
-import dataclasses
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.core.partition_manager import PartitionManager
-from repro.core.restart import NeedsLargerPartition
-from repro.core.tpu_slices import TpuPodBackend
-from repro.launch.mesh import make_slice_mesh
-from repro.models import registry
-from repro.core.memory.accountant import MemoryAccountant, pytree_nbytes
-from repro.core.memory.timeseries import PeakMemoryPredictor
+from repro.launch.mesh import host_pod_backend
+from repro.launch.tenants import TenantJob, run_pod
 
-
-def slice_devices(backend, handle):
-    """Map a buddy-tree handle to the concrete jax devices of the slice."""
-    devs = np.array(jax.devices()[:16]).reshape(4, 4)
-    x0, y0 = backend.slice_origin(handle)
-    sx, sy = backend.slice_shape(handle)
-    return devs[x0:x0 + sx, y0:y0 + sy]
-
-
-@dataclasses.dataclass
-class TenantJob:
-    name: str
-    n_tokens: int           # decode steps to run
-    growing: bool = False   # context growth -> predictor watches it
-
-
-def run_job_on_slice(job, cfg, params, mesh, partition_gb, predictor=None):
-    """Run a decode loop inside the slice's mesh; returns tokens or raises
-    NeedsLargerPartition when the predictor flags the growth."""
-    with mesh:
-        caches = registry.init_caches(cfg, batch=1, context=256)
-        decode = jax.jit(lambda p, t, i, c: registry.decode_step(p, cfg, t,
-                                                                 i, c))
-        acc = MemoryAccountant()
-        tok = jnp.zeros((1, 1), jnp.int32)
-        out = []
-        params_b = pytree_nbytes(params)
-        for i in range(job.n_tokens):
-            logits, caches = decode(params, tok, jnp.int32(i), caches)
-            tok = jnp.argmax(logits[:, :, :cfg.vocab], axis=-1).astype(
-                jnp.int32)
-            out.append(int(tok[0, 0]))
-            # allocator stats: params + the used cache prefix (+ synthetic
-            # growth for the 'growing' tenant to emulate a long context)
-            grow = (1.0 + 99.0 * i / job.n_tokens) if job.growing else 1.0
-            live = params_b + pytree_nbytes(caches) * grow * (i + 1) / 256
-            acc.note_alloc(live * 0.1 + params_b * 0.01)
-            acc.note_live(live)
-            acc.end_iteration()
-            if predictor is not None:
-                stats = acc.history[-1]
-                pred = predictor.observe(stats.requested_bytes,
-                                         stats.reuse_ratio)
-                if predictor.will_oom(partition_gb * 1024 ** 3, pred):
-                    raise NeedsLargerPartition(None)
-        return out
+#: per-chip HBM scaled so the reduced model's weights fill most of a chip
+CHIP_HBM_GB = 0.004
 
 
 def main() -> None:
-    assert jax.device_count() >= 16, "needs --xla_force_host_platform_device_count=16"
-    # a 4x4 'pod' of 16 host devices; tiny per-chip HBM so the demo's
-    # footprints are realistic for the reduced model
-    backend = TpuPodBackend(max_depth=4, pod_shape=(4, 4),
-                            chip_hbm_gb=0.002)
-    pm = PartitionManager(backend)
+    devices = jax.devices()[:4]
+    backend = host_pod_backend(devices, chip_hbm_gb=CHIP_HBM_GB)
     cfg = get_smoke_config("qwen3-0.6b")
-    params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
-
-    jobs = [TenantJob("tenant-a", 24), TenantJob("tenant-b", 24),
-            TenantJob("tenant-c-growing", 48, growing=True)]
-
-    # lease a tight slice per tenant FIRST — three co-resident partitions,
-    # each placement chosen by Alg. 3's reachability argmax
-    need_gb = pytree_nbytes(params) / 1024 ** 3 * 1.3
-    leases = []
-    for job in jobs:
-        profile = backend.tightest_profile(need_gb)
-        part = pm.allocate(profile) or pm.allocate_with_reshape(profile)
-        assert part is not None, f"no slice for {job.name}"
-        leases.append((job, profile, part))
-        print(f"{job.name}: leased {profile.name} at {part.handle}  "
-              f"(pod reachability now {backend.reachability(pm.state)})")
-    print(f"pod state with 3 tenants: {pm.describe()}\n")
-
-    for job, profile, part in leases:
-        devs = slice_devices(backend, part.handle)
-        mesh = make_slice_mesh(devs, devs.shape)
-        predictor = (PeakMemoryPredictor(max_iter=job.n_tokens,
-                                         converge_tol=0.3)
-                     if job.growing else None)
-        try:
-            toks = run_job_on_slice(job, cfg, params, mesh,
-                                    partition_gb=profile.mem_gb,
-                                    predictor=predictor)
-            print(f"  done: {len(toks)} tokens, first 8: {toks[:8]}")
-            pm.release(part)
-        except NeedsLargerPartition:
-            # the paper's early restart: free the tight slice, re-place on
-            # the next larger one, re-jit, continue — no checkpoint files
-            pm.release(part)
-            bigger = backend.next_larger_profile(profile)
-            part2 = pm.allocate(bigger) or pm.allocate_with_reshape(bigger)
-            assert part2 is not None
-            devs2 = slice_devices(backend, part2.handle)
-            mesh2 = make_slice_mesh(devs2, devs2.shape)
-            print(f"  EARLY RESTART -> {bigger.name} at {part2.handle} "
-                  f"({devs2.shape[0]}x{devs2.shape[1]} devices)")
-            toks = run_job_on_slice(job, cfg, params, mesh2,
-                                    partition_gb=bigger.mem_gb,
-                                    predictor=None)
-            print(f"  done after restart: {len(toks)} tokens")
-            pm.release(part2)
-
-    print(f"final state: {pm.describe()} (back to empty pod: "
-          f"{pm.state == backend.initial_state()})")
+    prompt = np.random.default_rng(0).integers(0, cfg.vocab, 8).astype(
+        np.int32)
+    jobs = [TenantJob("tenant-a", prompt, 24, seed=0),
+            TenantJob("tenant-b", prompt, 24, seed=1),
+            TenantJob("tenant-c-growing", prompt, 48, seed=2,
+                      grow_to_gb=1.5 * CHIP_HBM_GB)]
+    runs = run_pod(cfg, backend, jobs, devices=devices)
+    for run in runs:
+        print(f"  {run.job.name}: {len(run.tokens)} tokens on "
+              f"{run.profile.name} ({[d.id for d in run.devices.flat]})"
+              + (f", restarted from {run.restarted_from.name}"
+                 if run.restarted_from else ""))
 
 
 if __name__ == "__main__":

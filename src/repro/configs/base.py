@@ -68,7 +68,8 @@ class ModelConfig:
     # lookup (cheaper FLOPs, but XLA all-gathers around the sharded table)
     embed_impl: str = "onehot"
     # 'xla' = q-block-scanned exact attention; 'pallas' = the flash kernel
-    # (kernels/flash_attention.py; interpret-mode on CPU).  Chunked-mask
+    # (kernels/flash_attention.py; kernels.ops.resolve_interpret decides
+    # interpret mode: never on a TPU backend).  Chunked-mask
     # archs (llama4) fall back to xla for their local layers.
     attn_impl: str = "xla"
     # 'xla' = lax.scan chunked SSD; 'pallas' = kernels/ssd_scan.py

@@ -43,6 +43,22 @@ def early_restart_target(backend: PartitionBackend,
     return predicted_rung(backend, predicted_peak_gb, headroom)
 
 
+def host_restart_target(backend: PartitionBackend, current_gb: float | None,
+                        err: "NeedsLargerPartition") -> PartitionProfile:
+    """The slice a live early restart grows to.  A target that is not one of
+    ``backend``'s profiles (the predicted peak outgrows the host) or is no
+    larger than the current slice raises, so a restart loop cannot spin."""
+    nxt = err.profile
+    if (nxt is None or nxt not in backend.profiles
+            or nxt.mem_gb <= (current_gb or 0.0)):
+        largest = backend.profiles[-1]
+        raise RuntimeError(
+            f"early restart from the {current_gb}GB slice wants "
+            f"{nxt.name if nxt else 'a larger slice'}; the largest slice of "
+            f"this host is {largest.name} ({largest.mem_gb:.1f}GB)") from err
+    return nxt
+
+
 def migrate_state(state: Any, target_shardings: Any) -> Any:
     """Re-place a job's pytree state onto a new (larger) sub-mesh.
 

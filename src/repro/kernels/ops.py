@@ -1,9 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These adapt the model-layer layouts ([B,S,H,D]) to the kernel layouts
-([B,H,S,D]), pad ragged sequence lengths to block multiples, and expose an
-``interpret`` switch (CPU validation) — the model code calls these, never
-``pallas_call`` directly.
+([B,H,S,D]), pad ragged sequence lengths to block multiples, and decide in
+one place whether a kernel is interpreted (:func:`resolve_interpret`) — the
+model code calls these, never ``pallas_call`` directly.
 """
 
 from __future__ import annotations
@@ -15,10 +15,23 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
 
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode.
+
+    On a TPU backend a kernel is always compiled for the chip, whatever the
+    caller asked.  Elsewhere the default (``None``) interprets it; an
+    explicit ``False`` compiles it, which is how a kernel is compiled for a
+    described chip that is not attached.
+    """
+    if jax.default_backend() == "tpu":
+        return False
+    return True if interpret is None else interpret
+
+
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: int | None = None,
               block_q: int = 128, block_k: int = 128,
-              interpret: bool = False) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """Model-layout flash attention.
 
     q: [B,S,H,hd]; k/v: [B,S,KH,hd] -> [B,S,H,hd].
@@ -39,14 +52,14 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           v.transpose(0, 2, 1, 3), causal=causal,
                           window=window, block_q=min(block_q, q.shape[1]),
                           block_k=min(block_k, q.shape[1]),
-                          interpret=interpret)
+                          interpret=resolve_interpret(interpret))
     out = out.transpose(0, 2, 1, 3)
     return out[:, :s] if pad else out
 
 
 def ssd_mixer(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
               c_in: jax.Array, *, chunk: int = 128,
-              interpret: bool = False) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """Model-layout SSD: x [B,S,H,P], dt [B,S,H], a [H], b/c [B,S,N].
 
     Pads S to a chunk multiple with dt=0 (zero dt => exp(0)=1 decay and no
@@ -60,5 +73,5 @@ def ssd_mixer(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
         b_in = jnp.pad(b_in, ((0, 0), (0, pad), (0, 0)))
         c_in = jnp.pad(c_in, ((0, 0), (0, pad), (0, 0)))
     y = ssd_scan(x, dt, a, b_in, c_in, chunk=min(chunk, x.shape[1]),
-                 interpret=interpret)
+                 interpret=resolve_interpret(interpret))
     return y[:, :s] if pad else y

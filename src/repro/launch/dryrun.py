@@ -61,19 +61,6 @@ def _shard_tree(shapes_tree, specs_tree, mesh, rules, long_context):
         is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
 
 
-def _param_state(cfg: ModelConfig):
-    """(state ShapeDtypeStructs, spec tree) without allocating anything."""
-    holder = {}
-
-    def f(key):
-        params, specs = registry.init_params(key, cfg)
-        holder["specs"] = specs
-        return params
-
-    shapes = jax.eval_shape(f, jax.random.PRNGKey(0))
-    return shapes, holder["specs"]
-
-
 def _replicated(mesh):
     return NamedSharding(mesh, P())
 
@@ -84,7 +71,7 @@ def _replicated(mesh):
 def lower_train(cfg: ModelConfig, preset: ShapePreset, mesh,
                 policy: str = "baseline"):
     prules, arules = apply_policy(policy)
-    param_shapes, param_specs = _param_state(cfg)
+    param_shapes, param_specs = registry.abstract_params(cfg)
     big = param_count(cfg) > BIG_PARAM_THRESHOLD / 2
     mdtype = jnp.bfloat16 if big else jnp.float32
     mzeros = jax.tree_util.tree_map(
@@ -111,7 +98,7 @@ def lower_train(cfg: ModelConfig, preset: ShapePreset, mesh,
 def lower_prefill(cfg: ModelConfig, preset: ShapePreset, mesh,
                   policy: str = "baseline"):
     prules, arules = apply_policy(policy)
-    param_shapes, param_specs = _param_state(cfg)
+    param_shapes, param_specs = registry.abstract_params(cfg)
     p_sh = _shard_tree(param_shapes, param_specs, mesh, prules, False)
     batch_shapes = input_specs(cfg, preset)
     b_specs = registry.batch_specs(cfg, with_labels=False)
@@ -127,7 +114,7 @@ def lower_prefill(cfg: ModelConfig, preset: ShapePreset, mesh,
 def lower_decode(cfg: ModelConfig, preset: ShapePreset, mesh,
                  policy: str = "baseline"):
     prules, arules = apply_policy(policy)
-    param_shapes, param_specs = _param_state(cfg)
+    param_shapes, param_specs = registry.abstract_params(cfg)
     p_sh = _shard_tree(param_shapes, param_specs, mesh, prules, False)
     cache_shapes = jax.eval_shape(
         lambda: registry.init_caches(cfg, preset.batch, preset.seq))

@@ -5,23 +5,76 @@
 
 With ``--partition-gb`` the engine runs the time-series predictor against
 that slice size and performs the early restart (grow to the next profile)
-when the converged peak estimate exceeds it — the live §2.3 flow.
+when the converged peak estimate exceeds it — the live §2.3 flow.  The
+profiles it may grow to are the slices of the host's own pod
+(:func:`repro.launch.mesh.host_pod_backend`).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
-from repro.core.restart import NeedsLargerPartition
-from repro.core.tpu_slices import TpuPodBackend
+from repro.configs.base import ModelConfig
+from repro.core.partition_state import PartitionBackend, PartitionProfile
+from repro.core.restart import NeedsLargerPartition, host_restart_target
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import host_pod_backend
 from repro.models import registry
 from repro.serving.engine import EngineConfig, Request, ServeEngine
 from repro.training.checkpoint import load_checkpoint
+
+
+@dataclasses.dataclass
+class ServeResult:
+    requests: list[Request]
+    engine: ServeEngine            # the engine of the attempt that finished
+    profile_gb: float | None       # the slice size it finished on
+    restarts: list[PartitionProfile]
+    seconds: float                 # wall time of every attempt, restarts included
+
+
+def serve_with_early_restart(cfg: ModelConfig, params: dict,
+                             requests: list[Request], *,
+                             backend: PartitionBackend, max_context: int,
+                             partition_gb: float | None = None,
+                             log=print) -> ServeResult:
+    """Serve ``requests``, regrowing the slice each time the predictor
+    raises the early restart, until the batch finishes.
+
+    A restart is checkpointless: the batch is served again from its prompts
+    on the larger slice.  A target the host cannot give raises instead of
+    looping (:func:`repro.core.restart.host_restart_target`).
+    """
+    restarts: list[PartitionProfile] = []
+    t0 = time.perf_counter()
+    while True:
+        for r in requests:
+            r.generated.clear()
+        engine = ServeEngine(cfg, params,
+                             EngineConfig(max_batch=len(requests),
+                                          max_context=max_context,
+                                          partition_gb=partition_gb,
+                                          predict=partition_gb is not None),
+                             backend=backend)
+        try:
+            out = engine.run(requests)
+        except NeedsLargerPartition as e:
+            nxt = host_restart_target(backend, partition_gb, e)
+            log(f"[serve] EARLY RESTART: predictor flagged the "
+                f"{partition_gb:.1f}GB slice -> regrowing to "
+                f"{nxt.name} ({nxt.mem_gb:.1f}GB)")
+            restarts.append(nxt)
+            partition_gb = nxt.mem_gb
+            continue
+        return ServeResult(requests=out, engine=engine,
+                           profile_gb=partition_gb, restarts=restarts,
+                           seconds=time.perf_counter() - t0)
 
 
 def main() -> None:
@@ -37,52 +90,36 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     print(f"[serve] {cfg.name}: {cfg.n_layers}L d={cfg.d_model} "
           f"family={cfg.family}")
-    params, _ = registry.init_params(jax.random.PRNGKey(args.seed), cfg)
+    params, _ = registry.init_params_compiled(jax.random.PRNGKey(args.seed),
+                                              cfg)
     if args.ckpt:
         state = load_checkpoint(args.ckpt, {"params": jax.device_get(params)})
         params = state["params"]
         print(f"[serve] weights from {args.ckpt}")
 
-    backend = TpuPodBackend()
-    profile_gb = args.partition_gb
     rng = np.random.default_rng(args.seed)
     reqs = [Request(uid=i,
                     prompt=rng.integers(0, cfg.vocab, args.prompt_len
                                         ).astype(np.int32),
                     max_new_tokens=args.max_new)
             for i in range(args.requests)]
-
-    while True:
-        engine = ServeEngine(cfg, params,
-                             EngineConfig(max_batch=args.requests,
-                                          max_context=args.max_context,
-                                          partition_gb=profile_gb,
-                                          predict=profile_gb is not None),
-                             backend=backend)
-        t0 = time.time()
-        try:
-            out = engine.run(reqs)
-            dt = time.time() - t0
-            n_tok = sum(len(r.generated) for r in out)
-            print(f"[serve] {n_tok} tokens in {dt:.1f}s "
-                  f"({n_tok / max(dt, 1e-9):.1f} tok/s)")
-            for r in out[:4]:
-                print(f"  req {r.uid}: {r.generated[:16]}"
-                      f"{'...' if len(r.generated) > 16 else ''}")
-            peak = engine.accountant.peak_in_use / 1024 ** 3
-            print(f"[serve] peak live memory {peak:.3f} GB over "
-                  f"{len(engine.accountant.history)} iterations")
-            break
-        except NeedsLargerPartition as e:
-            nxt = e.profile or backend.tightest_profile(
-                (profile_gb or 1.0) * 2)
-            print(f"[serve] EARLY RESTART: predictor flagged the "
-                  f"{profile_gb:.1f}GB slice -> regrowing to "
-                  f"{nxt.name} ({nxt.mem_gb:.1f}GB)")
-            profile_gb = nxt.mem_gb
+    res = serve_with_early_restart(cfg, params, reqs,
+                                   backend=host_pod_backend(),
+                                   max_context=args.max_context,
+                                   partition_gb=args.partition_gb)
+    n_tok = sum(len(r.generated) for r in res.requests)
+    print(f"[serve] {n_tok} tokens in {res.seconds:.1f}s "
+          f"({n_tok / max(res.seconds, 1e-9):.1f} tok/s)")
+    for r in res.requests[:4]:
+        print(f"  req {r.uid}: {r.generated[:16]}"
+              f"{'...' if len(r.generated) > 16 else ''}")
+    acc = res.engine.accountant
+    print(f"[serve] peak live memory {acc.peak_in_use / 1024 ** 3:.3f} GB "
+          f"over {len(acc.history)} iterations")
 
 
 if __name__ == "__main__":

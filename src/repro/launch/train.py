@@ -16,6 +16,7 @@ import time
 import jax
 
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training.checkpoint import load_checkpoint, save_checkpoint
 from repro.training.data import DataConfig, SyntheticLM
 from repro.training.optimizer import AdamWConfig
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     print(f"[train] {cfg.name} ({'smoke' if args.smoke else 'FULL'}): "
           f"{cfg.n_layers}L d={cfg.d_model} family={cfg.family} on "

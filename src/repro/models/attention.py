@@ -128,10 +128,8 @@ def mha_full(params: dict, x: jax.Array, cfg: ModelConfig,
     static_window = isinstance(window, int) or window is None
     if (cfg.attn_impl == "pallas" and chunk is None and causal
             and static_window):
-        # the Pallas flash kernel: interpret-mode executes on CPU
         from repro.kernels.ops import flash_mha
-        out = flash_mha(q, k, v, causal=True, window=window,
-                        interpret=jax.default_backend() == "cpu")
+        out = flash_mha(q, k, v, causal=True, window=window)
     elif s <= q_block or s % q_block != 0:
         bias = _mask_bias(pos, pos, window, chunk, causal)
         out = _sdpa(q, k, v, bias, cfg)

@@ -10,6 +10,8 @@ A "batch" is a dict:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -26,6 +28,34 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> tuple[dict, dict]:
     if cfg.family == "hybrid":
         return hybrid.init_hybrid(key, cfg)
     return transformer.init_decoder(key, cfg)
+
+
+def abstract_params(cfg: ModelConfig) -> tuple[dict, dict]:
+    """(ShapeDtypeStruct tree, logical-axis spec tree) of
+    :func:`init_params`, without allocating anything."""
+    holder = {}
+
+    def build(key):
+        params, holder["specs"] = init_params(key, cfg)
+        return params
+
+    shapes = jax.eval_shape(build, jax.random.PRNGKey(0))
+    return shapes, holder["specs"]
+
+
+@functools.lru_cache(maxsize=None)
+def _init_program(cfg: ModelConfig):
+    return jax.jit(lambda key: init_params(key, cfg)[0])
+
+
+def init_params_compiled(key: jax.Array, cfg: ModelConfig
+                         ) -> tuple[dict, dict]:
+    """:func:`init_params` as one compiled program, on the default device.
+
+    Eager init dispatches, and on an accelerator compiles, every op of
+    every parameter: minutes for a full-width model on a TPU.  One program
+    per config compiles once."""
+    return _init_program(cfg)(key), abstract_params(cfg)[1]
 
 
 def forward(params: dict, cfg: ModelConfig, batch: dict) -> DecoderOutput:

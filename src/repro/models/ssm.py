@@ -140,8 +140,7 @@ def ssm_forward(params: dict, x: jax.Array, cfg: ModelConfig
     if cfg.ssm_impl == "pallas":
         from repro.kernels.ops import ssd_mixer
         y = ssd_mixer(x_heads, dt, a, b_ssm.astype(jnp.float32),
-                      c_ssm.astype(jnp.float32), chunk=cfg.ssm_chunk,
-                      interpret=jax.default_backend() == "cpu")
+                      c_ssm.astype(jnp.float32), chunk=cfg.ssm_chunk)
     else:
         y, _ = ssd_chunked(x_heads, dt, a, b_ssm, c_ssm, cfg.ssm_chunk)
     y = y + params["D"].astype(y.dtype)[None, None, :, None] * x_heads

@@ -45,6 +45,13 @@ def chunked_flags(cfg: ModelConfig) -> bool:
     return cfg.attention_chunk is not None
 
 
+def all_layers_global(cfg: ModelConfig) -> bool:
+    """No local layers: every layer's window is statically "none", so the
+    full-sequence attention may take the flash kernel, whose window must be
+    static (a traced per-layer window keeps it on the XLA path)."""
+    return cfg.sliding_window is None and cfg.attention_chunk is None
+
+
 def windowed_layout(cfg: ModelConfig) -> tuple[int, int, int]:
     """(n_groups, group_size, n_tail) for the windowed-cache decode layout:
     groups of (global_every) layers = (ge-1) local + 1 global; trailing
@@ -119,6 +126,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(jnp.arange(s), (b_, s))
     windows = layer_pattern(cfg)
     is_chunked = chunked_flags(cfg)
+    no_window = is_chunked or all_layers_global(cfg)
 
     if cfg.family == "ssm":
         @remat_layer
@@ -137,7 +145,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
             lp, win = xs
             window = jnp.where(win >= GLOBAL, jnp.int32(2 ** 30), win)
             chunk = window if is_chunked else None
-            w_arg = None if is_chunked else window
+            w_arg = None if no_window else window
             h = h + attn.mha_full(lp, rmsnorm(h, lp["norm1"], cfg.norm_eps),
                                   cfg, positions, window=w_arg, chunk=chunk)
             hn = rmsnorm(h, lp["norm2"], cfg.norm_eps)

@@ -67,8 +67,12 @@ class ServeEngine:
         self.backend = backend
         self._reset_run_state()
         self._params_bytes = pytree_nbytes(params)
+        # the caches are donated: each step writes them in place.  Without
+        # it every step queued ahead of the device holds a fresh copy of
+        # the whole cache, and the queue fills the chip's HBM.
         self._decode = jax.jit(
-            lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c))
+            lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c),
+            donate_argnums=(3,))
 
     def _reset_run_state(self) -> None:
         """Fresh per-run accounting: a second batch on the same engine must
@@ -102,6 +106,8 @@ class ServeEngine:
         for pos in range(prompt_len):
             logits, caches = self._decode(self.params, batch["tokens"][:, pos:pos + 1],
                                           jnp.int32(pos), caches)
+        #: logits at the last prompt position, kept for reference checks
+        self.prompt_logits = logits
         self._note_iteration(caches, prompt_len)
 
         # decode

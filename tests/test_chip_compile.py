@@ -1,0 +1,97 @@
+"""Compile the main path for a described TPU v5e chip — no chip needed.
+
+The TPU compiler refuses what interpret mode accepts: blocks off the (8, 128)
+tiling, ops Mosaic cannot lower, programs that overflow HBM.  These tests
+compile the Pallas kernels (``interpret=False``) at the widths of the models
+that use them, and one full-width qwen3-1.7b decode step, for a chip that is
+described and not attached.  Nothing runs, so they say nothing about results
+or times.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and under several test workers only
+the worker given this file may.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ssd_scan import ssd_scan
+from repro.models import registry
+from repro.models.ssm import ssm_dims
+
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compilation cache off: a
+    compile for a chip that is not attached cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_flash_attention_compiles_at_qwen3_widths(one_chip, window):
+    cfg = get_config("qwen3-1.7b")
+    hd, s = cfg.resolved_head_dim, 2048
+    q = _sds((1, cfg.n_heads, s, hd), jnp.bfloat16, one_chip)
+    kv = _sds((1, cfg.n_kv_heads, s, hd), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, interpret=False)
+    ).lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
+    cfg = get_config("mamba2-2.7b")
+    _, h, p, n = ssm_dims(cfg)
+    b, s = 1, 2048
+    f32 = jnp.float32
+    compiled = jax.jit(lambda *a: ssd_scan(
+        *a, chunk=cfg.ssm_chunk, interpret=False)).lower(
+        _sds((b, s, h, p), jnp.bfloat16, one_chip),
+        _sds((b, s, h), f32, one_chip), _sds((h,), f32, one_chip),
+        _sds((b, s, n), f32, one_chip), _sds((b, s, n), f32, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen3_decode_step_fits_one_chip(one_chip):
+    cfg = get_config("qwen3-1.7b")
+    batch, context = 8, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = on_chip(registry.abstract_params(cfg)[0])
+    caches = on_chip(jax.eval_shape(
+        lambda: registry.init_caches(cfg, batch, context)))
+    compiled = jax.jit(
+        lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c)).lower(
+        params, _sds((batch, 1), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), caches).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
