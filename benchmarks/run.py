@@ -20,8 +20,8 @@ from benchmarks import (bench_breakdown, bench_cluster, bench_elastic,
                         bench_fig4_general, bench_fig4_ml, bench_fleet,
                         bench_kernel, bench_kernels, bench_obs,
                         bench_planner, bench_predictor, bench_reachability,
-                        bench_regret, bench_roofline, bench_router,
-                        bench_serving, bench_slo, bench_tpu_pod)
+                        bench_regret, bench_router, bench_serving,
+                        bench_slo, bench_tpu_pod)
 
 #: Bump when the BENCH_<name>.json layout changes incompatibly;
 #: ``benchmarks/compare.py`` refuses baselines from another schema.
@@ -35,7 +35,6 @@ BENCHES = {
     "planner": bench_planner.run,             # compiled graph vs seed Alg. 3
     "breakdown": bench_breakdown.run,         # paper Tables 3-4
     "kernels": bench_kernels.run,             # Pallas kernel paths
-    "roofline": bench_roofline.run,           # §Roofline (dry-run derived)
     "tpu_pod": bench_tpu_pod.run,             # the TPU adaptation, end-to-end
     "fleet": bench_fleet.run,                 # multi-GPU fleet routing
     "serving": bench_serving.run,             # request-level LLM serving SLOs

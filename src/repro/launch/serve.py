@@ -26,6 +26,7 @@ from repro.core.restart import NeedsLargerPartition, host_restart_target
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import host_pod_backend
 from repro.models import registry
+from repro.obs.spans import span
 from repro.serving.engine import EngineConfig, Request, ServeEngine
 from repro.training.checkpoint import load_checkpoint
 
@@ -56,19 +57,25 @@ def serve_with_early_restart(cfg: ModelConfig, params: dict,
     while True:
         for r in requests:
             r.generated.clear()
-        engine = ServeEngine(cfg, params,
-                             EngineConfig(max_batch=len(requests),
-                                          max_context=max_context,
-                                          partition_gb=partition_gb,
-                                          predict=partition_gb is not None),
-                             backend=backend)
+            r.token_times.clear()
         try:
-            out = engine.run(requests)
+            with span("serve.attempt", attempt=len(restarts),
+                      slice_gb=partition_gb, rows=len(requests)):
+                engine = ServeEngine(
+                    cfg, params,
+                    EngineConfig(max_batch=len(requests),
+                                 max_context=max_context,
+                                 partition_gb=partition_gb,
+                                 predict=partition_gb is not None),
+                    backend=backend)
+                out = engine.run(requests)
         except NeedsLargerPartition as e:
-            nxt = host_restart_target(backend, partition_gb, e)
-            log(f"[serve] EARLY RESTART: predictor flagged the "
-                f"{partition_gb:.1f}GB slice -> regrowing to "
-                f"{nxt.name} ({nxt.mem_gb:.1f}GB)")
+            with span("serve.restart", from_gb=partition_gb) as mark:
+                nxt = host_restart_target(backend, partition_gb, e)
+                mark.set_metadata(to_gb=nxt.mem_gb)
+                log(f"[serve] EARLY RESTART: predictor flagged the "
+                    f"{partition_gb:.1f}GB slice -> regrowing to "
+                    f"{nxt.name} ({nxt.mem_gb:.1f}GB)")
             restarts.append(nxt)
             partition_gb = nxt.mem_gb
             continue
@@ -117,9 +124,13 @@ def main() -> None:
     for r in res.requests[:4]:
         print(f"  req {r.uid}: {r.generated[:16]}"
               f"{'...' if len(r.generated) > 16 else ''}")
+    peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+    if peak is not None:
+        print(f"[serve] device peak memory in use {peak / 1024 ** 3:.3f} GB")
     acc = res.engine.accountant
-    print(f"[serve] peak live memory {acc.peak_in_use / 1024 ** 3:.3f} GB "
-          f"over {len(acc.history)} iterations")
+    print(f"[serve] predictor's estimate of peak live memory "
+          f"{acc.peak_in_use / 1024 ** 3:.3f} GB over {len(acc.history)} "
+          f"iterations")
 
 
 if __name__ == "__main__":

@@ -97,8 +97,11 @@ def decode_on_slice(cfg: ModelConfig, params: dict, mesh: Mesh,
     # outputs pinned to the input layout: the step compiles once per slice
     logits_sharding = NamedSharding(mesh, act_spec(
         ("batch", "seq", "vocab"), mesh, (1, 1, padded_vocab(cfg))))
+
+    def decode_step(p, t, i, c):
+        return registry.decode_step(p, cfg, t, i, c)
     step = jax.jit(
-        lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c),
+        decode_step,
         out_shardings=(logits_sharding,
                        jax.tree.map(lambda c: c.sharding, caches)))
     seq = [int(t) for t in prompt]

@@ -45,7 +45,9 @@ def abstract_params(cfg: ModelConfig) -> tuple[dict, dict]:
 
 @functools.lru_cache(maxsize=None)
 def _init_program(cfg: ModelConfig):
-    return jax.jit(lambda key: init_params(key, cfg)[0])
+    def init_params_program(key):
+        return init_params(key, cfg)[0]
+    return jax.jit(init_params_program)
 
 
 def init_params_compiled(key: jax.Array, cfg: ModelConfig

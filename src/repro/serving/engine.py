@@ -11,7 +11,9 @@ multi-tenant launcher migrates the job to a bigger sub-slice.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -23,8 +25,10 @@ from repro.core.memory.timeseries import PeakMemoryPredictor
 from repro.core.restart import NeedsLargerPartition, early_restart_target
 from repro.core.partition_state import PartitionBackend, PartitionProfile
 from repro.models import registry
+from repro.obs.spans import compile_log, span
 
 GB = 1024 ** 3
+_LOADED = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -33,6 +37,9 @@ class Request:
     prompt: np.ndarray            # [S] int32
     max_new_tokens: int
     generated: list[int] = dataclasses.field(default_factory=list)
+    #: ``time.perf_counter()`` at which each token of ``generated`` reached
+    #: the host: one stamp per decode step, shared by the batch's rows
+    token_times: list[float] = dataclasses.field(default_factory=list)
 
     @property
     def done(self) -> bool:
@@ -67,12 +74,15 @@ class ServeEngine:
         self.backend = backend
         self._reset_run_state()
         self._params_bytes = pytree_nbytes(params)
+        compile_log()       # listening before this engine's step compiles
+
+        def decode_step(p, t, i, c):
+            return registry.decode_step(p, cfg, t, i, c)
         # the caches are donated: each step writes them in place.  Without
         # it every step queued ahead of the device holds a fresh copy of
         # the whole cache, and the queue fills the chip's HBM.
-        self._decode = jax.jit(
-            lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c),
-            donate_argnums=(3,))
+        self._decode = jax.jit(decode_step, donate_argnums=(3,))
+        self._loaded = False
 
     def _reset_run_state(self) -> None:
         """Fresh per-run accounting: a second batch on the same engine must
@@ -90,42 +100,62 @@ class ServeEngine:
         self._reset_run_state()
         b = len(requests)
         prompt_len = max(len(r.prompt) for r in requests)
-        caches = registry.init_caches(cfg, b, ecfg.max_context)
-
-        # prefill (teacher-forced forward over the padded prompt batch)
-        toks = np.zeros((b, prompt_len), np.int32)
-        for i, r in enumerate(requests):
-            toks[i, :len(r.prompt)] = r.prompt
-        batch = {"tokens": jnp.asarray(toks)}
-        if cfg.family == "audio":
-            batch["frames"] = jnp.zeros((b, cfg.enc_seq, cfg.d_model),
-                                        jnp.bfloat16)
-            caches = registry.prefill_encoder(self.params, cfg, batch, caches)
-        # replay the prompt through decode_step to fill the KV cache
-        logits = None
-        for pos in range(prompt_len):
-            logits, caches = self._decode(self.params, batch["tokens"][:, pos:pos + 1],
-                                          jnp.int32(pos), caches)
-        #: logits at the last prompt position, kept for reference checks
-        self.prompt_logits = logits
-        self._note_iteration(caches, prompt_len)
-
-        # decode
-        next_tok = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1)[:, None]
-        for step in range(max(r.max_new_tokens for r in requests)):
-            pos = prompt_len + step
-            if pos >= ecfg.max_context:
-                break
-            logits, caches = self._decode(self.params,
-                                          next_tok.astype(jnp.int32),
-                                          jnp.int32(pos), caches)
-            next_tok = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1)[:, None]
-            toks_np = np.asarray(next_tok[:, 0])
+        with span("engine.setup"):
+            caches = registry.init_caches(cfg, b, ecfg.max_context)
+            # prefill (teacher-forced forward over the padded prompt batch)
+            toks = np.zeros((b, prompt_len), np.int32)
             for i, r in enumerate(requests):
-                if not r.done:
-                    r.generated.append(int(toks_np[i]))
-            self._check_memory(caches, pos)
+                toks[i, :len(r.prompt)] = r.prompt
+            batch = {"tokens": jnp.asarray(toks)}
+            if cfg.family == "audio":
+                batch["frames"] = jnp.zeros((b, cfg.enc_seq, cfg.d_model),
+                                            jnp.bfloat16)
+                caches = registry.prefill_encoder(self.params, cfg, batch,
+                                                  caches)
+        # replay the prompt through decode_step to fill the KV cache.  The
+        # step is dispatched here and in the decode loop, not through a
+        # helper method: called from one, its first call lowered 3.5 times
+        # slower on a TPU v5e host (0.31-0.36 s against 0.09 s), every engine
+        logits = None
+        with span("engine.replay", positions=prompt_len):
+            for pos in range(prompt_len):
+                with self._load_span():
+                    logits, caches = self._decode(
+                        self.params, batch["tokens"][:, pos:pos + 1],
+                        jnp.int32(pos), caches)
+            #: logits at the last prompt position, kept for reference checks
+            self.prompt_logits = logits
+            self._note_iteration(caches, prompt_len)
+
+        steps = min(max(r.max_new_tokens for r in requests),
+                    ecfg.max_context - prompt_len)
+        with span("engine.decode", steps=steps):
+            next_tok = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1)[:, None]
+            for step in range(steps):
+                pos = prompt_len + step
+                logits, caches = self._decode(self.params,
+                                              next_tok.astype(jnp.int32),
+                                              jnp.int32(pos), caches)
+                next_tok = jnp.argmax(logits[:, -1, :cfg.vocab],
+                                      axis=-1)[:, None]
+                row = next_tok[:, 0]
+                with span("engine.sync"):
+                    toks_np = np.asarray(row)
+                now = time.perf_counter()
+                for i, r in enumerate(requests):
+                    if not r.done:
+                        r.generated.append(int(toks_np[i]))
+                        r.token_times.append(now)
+                self._check_memory(caches, pos)
         return requests
+
+    def _load_span(self):
+        """``repro.engine.load`` for a new engine's first step, which traces,
+        lowers and compiles (or loads) it; a no-op for the steps after."""
+        if self._loaded:
+            return _LOADED
+        self._loaded = True
+        return span("engine.load")
 
     # -- instrumentation (paper §3.2.2) --------------------------------------------
 
@@ -164,19 +194,20 @@ class ServeEngine:
         return self.predictor.will_oom(partition_bytes, pred)
 
     def _check_memory(self, caches, upto: int) -> None:
-        self._note_iteration(caches, upto)
-        if not (self.ecfg.predict and self.ecfg.partition_gb):
-            return
-        stats = self.accountant.history[-1]
-        pred = self.predictor.observe(stats.requested_bytes,
-                                      stats.reuse_ratio)
-        if self._restart_now(self.ecfg.partition_gb * GB, pred):
-            target = None
-            if self.backend is not None:
-                target = early_restart_target(self.backend,
-                                              pred.peak_mem_bytes / GB)
-            raise NeedsLargerPartition(
-                target or _synthetic_profile(pred.peak_mem_bytes / GB))
+        with span("engine.predictor"):
+            self._note_iteration(caches, upto)
+            if not (self.ecfg.predict and self.ecfg.partition_gb):
+                return
+            stats = self.accountant.history[-1]
+            pred = self.predictor.observe(stats.requested_bytes,
+                                          stats.reuse_ratio)
+            if self._restart_now(self.ecfg.partition_gb * GB, pred):
+                target = None
+                if self.backend is not None:
+                    target = early_restart_target(self.backend,
+                                                  pred.peak_mem_bytes / GB)
+                raise NeedsLargerPartition(
+                    target or _synthetic_profile(pred.peak_mem_bytes / GB))
 
 
 def _synthetic_profile(mem_gb: float) -> PartitionProfile:
