@@ -140,29 +140,72 @@ def mha_full(params: dict, x: jax.Array, cfg: ModelConfig,
     return constrain(y, ("batch", "seq", None))
 
 
-def mha_decode(params: dict, x: jax.Array, cfg: ModelConfig,
-               cache_k: jax.Array, cache_v: jax.Array, index: jax.Array,
-               window=None, chunk=None):
-    """One-token decode. x:[B,1,d]; cache_k/v:[B,C,KH,hd]; index: scalar
-    current position.  Returns (y, cache_k, cache_v)."""
-    positions = jnp.full((x.shape[0], 1), index, jnp.int32)
-    q, k_new, v_new = _project_qkv(params, x, cfg, positions,
-                                   rope=not _no_rope(cfg))
-    cache_k = jax.lax.dynamic_update_slice_in_dim(
-        cache_k, k_new.astype(cache_k.dtype), index, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(
-        cache_v, v_new.astype(cache_v.dtype), index, axis=1)
-    c = cache_k.shape[1]
-    k_pos = jnp.arange(c)
-    valid = k_pos <= index
+def _window_valid(k_pos: jax.Array, index: jax.Array, window, chunk
+                  ) -> jax.Array:
+    """Which cached positions the sliding-window and chunk terms let the
+    token at ``index`` see; the causal term is the caller's."""
+    valid = jnp.ones(k_pos.shape, jnp.bool_)
     if window is not None:
         valid &= (index - k_pos) < window
     if chunk is not None:
         valid &= (k_pos // chunk) == (index // chunk)
-    bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)[None, :]
-    out = _sdpa(q, cache_k.astype(q.dtype), cache_v.astype(q.dtype), bias, cfg)
+    return valid
+
+
+def mha_decode_append(params: dict, x: jax.Array, cfg: ModelConfig,
+                      cache_k: jax.Array, cache_v: jax.Array,
+                      index: jax.Array, window=None, chunk=None):
+    """One-token decode that leaves the cache to the caller.
+
+    x:[B,1,d]; cache_k/v:[B,C,KH,hd], read only: positions ``< index`` are
+    attended from the cache and the token at ``index`` from its own k/v,
+    so nothing cache-sized is written here.  Returns (y, k_new, v_new),
+    k_new/v_new [B,1,KH,hd] in the cache's dtype, for the caller to write
+    at ``index`` (:func:`mha_decode` does that write itself).  Scores and
+    softmax are f32; the new token is rounded through the cache's dtype,
+    as it would be had it been read back from the cache.
+    """
+    positions = jnp.full((x.shape[0], 1), index, jnp.int32)
+    q, k_new, v_new = _project_qkv(params, x, cfg, positions,
+                                   rope=not _no_rope(cfg))
+    k_new = k_new.astype(cache_k.dtype)
+    v_new = v_new.astype(cache_v.dtype)
+    b_, _, h, hd = q.shape
+    c, kh = cache_k.shape[1], cache_k.shape[2]
+    dt, f32 = q.dtype, jnp.float32
+    k_pos = jnp.arange(c)
+    valid = (k_pos < index) & _window_valid(k_pos, index, window, chunk)
+    bias = jnp.where(valid, 0.0, NEG_INF).astype(f32)
+    qg = q.reshape(b_, kh, h // kh, hd)
+    scale = jnp.sqrt(jnp.asarray(hd, f32))
+    s_cache = jnp.einsum("bkgd,bskd->bkgs", qg, cache_k.astype(dt)
+                         ).astype(f32) / scale + bias
+    s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new[:, 0].astype(dt)
+                       ).astype(f32) / scale
+    probs = jax.nn.softmax(
+        jnp.concatenate([s_cache, s_new[..., None]], axis=-1), axis=-1
+    ).astype(dt)
+    out = (jnp.einsum("bkgs,bskd->bkgd", probs[..., :c], cache_v.astype(dt),
+                      preferred_element_type=f32)
+           + jnp.einsum("bkg,bkd->bkgd", probs[..., c], v_new[:, 0].astype(dt),
+                        preferred_element_type=f32))
+    out = constrain(out.astype(dt).reshape(b_, 1, h, hd),
+                    ("batch", "seq", "heads", None))
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return constrain(y, ("batch", "seq", None)), cache_k, cache_v
+    return constrain(y, ("batch", "seq", None)), k_new, v_new
+
+
+def mha_decode(params: dict, x: jax.Array, cfg: ModelConfig,
+               cache_k: jax.Array, cache_v: jax.Array, index: jax.Array,
+               window=None, chunk=None):
+    """One-token decode that writes its own cache: :func:`mha_decode_append`
+    with k/v then written at ``index``.  x:[B,1,d]; cache_k/v:[B,C,KH,hd];
+    index: scalar current position.  Returns (y, cache_k, cache_v)."""
+    y, k_new, v_new = mha_decode_append(params, x, cfg, cache_k, cache_v,
+                                        index, window=window, chunk=chunk)
+    upd = jax.lax.dynamic_update_slice_in_dim
+    return (y, upd(cache_k, k_new, index, axis=1),
+            upd(cache_v, v_new, index, axis=1))
 
 
 def _attend(q, k, v, q_pos, k_pos, window, chunk, causal, cfg,
@@ -295,13 +338,8 @@ def mha_decode_quant(params: dict, x: jax.Array, cfg: ModelConfig,
     k_s = upd(k_s, kns, index, axis=1)
     v_q = upd(v_q, vnq, index, axis=1)
     v_s = upd(v_s, vns, index, axis=1)
-    c = k_q.shape[1]
-    k_pos = jnp.arange(c)
-    valid = k_pos <= index
-    if window is not None:
-        valid &= (index - k_pos) < window
-    if chunk is not None:
-        valid &= (k_pos // chunk) == (index // chunk)
+    k_pos = jnp.arange(k_q.shape[1])
+    valid = (k_pos <= index) & _window_valid(k_pos, index, window, chunk)
     bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)[None, :]
     k = dequantize_kv(k_q, k_s, q.dtype)
     v = dequantize_kv(v_q, v_s, q.dtype)

@@ -314,7 +314,7 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
             window = jnp.where(win >= GLOBAL, jnp.int32(2 ** 30), win)
             chunk = window if is_chunked else None
             w_arg = None if is_chunked else window
-            out, ck, cv = attn.mha_decode(
+            out, k_new, v_new = attn.mha_decode_append(
                 lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg, ck, cv,
                 index, window=w_arg, chunk=chunk)
             h = h + out
@@ -323,11 +323,15 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
                 out2, _ = moe_lib.moe_layer(lp, hn, cfg)
             else:
                 out2 = mlp(lp, hn, cfg)
-            return h + out2, (ck, cv)
+            return h + out2, (k_new, v_new)
 
-        x, (ks, vs) = jax.lax.scan(
+        # the layers read the cache and return only the new position; the
+        # donated stacks then take it in place, [L, B, 1, KH, hd] each
+        x, (k_new, v_new) = jax.lax.scan(
             body, x, (params["layers"], caches["k"], caches["v"], windows))
-        caches = {"k": ks, "v": vs}
+        upd = jax.lax.dynamic_update_slice_in_dim
+        caches = {"k": upd(caches["k"], k_new, index, axis=2),
+                  "v": upd(caches["v"], v_new, index, axis=2)}
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, x, cfg)

@@ -103,20 +103,38 @@ def test_decode_step_cache_invariants(arch, smoke_state):
     assert shapes_ok
 
 
-@pytest.mark.parametrize("arch", ["qwen3-0.6b", "gemma3-27b", "mamba2-2.7b",
-                                  "zamba2-7b", "whisper-medium"])
-def test_prefill_decode_consistency(arch, smoke_state):
+@pytest.mark.parametrize(
+    "arch, context",
+    [("qwen3-0.6b", 16), ("gemma3-27b", 16), ("mamba2-2.7b", 16),
+     ("zamba2-7b", 16), ("whisper-medium", 16),
+     # MoE MLP and the VLM decoder in the plain attention body
+     ("grok-1-314b", 16), ("pixtral-12b", 16),
+     # the last step writes the cache's last slot (index == C - 1)
+     ("qwen3-0.6b", 8)],
+    ids=["qwen3-0.6b", "gemma3-27b", "mamba2-2.7b", "zamba2-7b",
+         "whisper-medium", "grok-1-314b", "pixtral-12b",
+         "qwen3-0.6b-last-slot"])
+def test_prefill_decode_consistency(arch, context, smoke_state):
     """Teacher-forced logits == step-by-step decode logits (f32)."""
+    import dataclasses
+
     from repro.models.module import cast_tree
     cfg, params, _ = smoke_state(arch)
+    if cfg.n_experts:
+        # room for every token in the forward's expert queues: a decode
+        # step of one token never drops one
+        cfg = dataclasses.replace(
+            cfg, capacity_factor=cfg.n_experts / cfg.top_k)
     params32 = cast_tree(params, jnp.float32)
     S = 8
     batch = registry.make_dummy_batch(cfg, BATCH, S,
                                       key=jax.random.PRNGKey(7))
+    # decode embeds tokens only, so the VLM's forward takes no patches
+    batch.pop("patches", None)
     batch = {k: (v.astype(jnp.float32) if v.dtype == jnp.bfloat16 else v)
              for k, v in batch.items()}
     full = registry.forward(params32, cfg, batch).logits
-    caches = registry.init_caches(cfg, BATCH, 16)
+    caches = registry.init_caches(cfg, BATCH, context)
     caches = jax.tree_util.tree_map(
         lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
         caches)

@@ -3,14 +3,16 @@
 The TPU compiler refuses what interpret mode accepts: blocks off the (8, 128)
 tiling, ops Mosaic cannot lower, programs that overflow HBM.  These tests
 compile the Pallas kernels (``interpret=False``) at the widths of the models
-that use them, and one full-width qwen3-1.7b decode step, for a chip that is
-described and not attached.  Nothing runs, so they say nothing about results
-or times.
+that use them, and the full-width qwen3-1.7b decode step, for a chip that is
+described and not attached: it must fit, and must write its donated caches in
+place.  Nothing runs, so they say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under several test workers only
 the worker given this file may.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,3 +97,31 @@ def test_qwen3_decode_step_fits_one_chip(one_chip):
         _sds((), jnp.int32, one_chip), caches).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_qwen3_decode_step_writes_cache_in_place(one_chip):
+    """At decode-long's shape (64 rows, context 512) the compiled step
+    holds no cache-sized temporary and copies no cache stack: the donated
+    caches take the new position in place."""
+    cfg = get_config("qwen3-1.7b")
+    batch, context = 64, 512
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = on_chip(registry.abstract_params(cfg)[0])
+    caches = on_chip(jax.eval_shape(
+        lambda: registry.init_caches(cfg, batch, context)))
+    compiled = jax.jit(
+        lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c),
+        donate_argnums=(3,)).lower(
+        params, _sds((batch, 1), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), caches).compile()
+    layer_bytes = caches["k"].size // cfg.n_layers * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    stack = "bf16[{},{},{},{},{}]".format(*caches["k"].shape)
+    ops = re.findall(r"^\s*(?:ROOT )?%(\S+) = (\S+)", compiled.as_text(),
+                     re.M)
+    copies = [name for name, typ in ops
+              if name.startswith("copy") and typ.startswith(stack)]
+    assert not copies, copies
