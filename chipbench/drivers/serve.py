@@ -52,6 +52,10 @@ def reparametrize(weights: dict, scale: float) -> dict:
 class Driver:
     def __init__(self, ctx) -> None:
         self.ctx, self.mix, self.hp = ctx, ctx.mix, ctx.config
+        #: the published vocabulary: the ids a user can send or get back.
+        #: The program's table may hold more rows (padding), which are no
+        #: token
+        self.vocab = self.hp["vocab_size"]
 
     # -- set-up ------------------------------------------------------------
 
@@ -72,11 +76,14 @@ class Driver:
         if mine != theirs:
             raise RuntimeError(f"the program's weights are laid out as "
                                f"{theirs}, the reference's as {mine}")
+        if self.vocab > rows:
+            raise RuntimeError(f"vocab_size {self.vocab} is more than the "
+                               f"program's {rows} rows")
         scale = input_scale(cfg)
         self.params = jax.jit(lambda key: reparametrize(
             self.ref_init(key), scale))(self.ctx.key(0))
         self.backend = host_pod_backend(self.ctx.devices)
-        self.traffic = traffic_lib.Traffic(self.mix, self.ctx.seed, cfg.vocab)
+        self.traffic = traffic_lib.Traffic(self.mix, self.ctx.seed, self.vocab)
         #: batches in one cycle of the traffic: the window ends on one
         self.cycle = self.mix["levels"]
 
@@ -114,9 +121,11 @@ class Driver:
                 partition_gb=self.mix["start_slice_gb"], log=on_restart)
             jax.block_until_ready(res.engine.prompt_logits)
         t_return = time.perf_counter()
-        # the served first tokens: the argmax at the last prompt position.
-        # Only they leave the batch; a batch's logits kept on the device
-        # through the window fragment its memory
+        # the served first tokens: the argmax at the last prompt position
+        # over every row of the program's table, as the engine feeds it
+        # forward; one past the published vocabulary is served wrong.  Only
+        # they leave the batch; a batch's logits kept on the device through
+        # the window fragment its memory
         first = np.asarray(jnp.argmax(
             res.engine.prompt_logits[:, -1, :self.cfg.vocab], axis=-1))
         return {"t_issue": t_issue, "t_return": t_return, "restarts": marks,
@@ -170,11 +179,14 @@ class Driver:
 
     def incomplete(self, rec: dict) -> int:
         """Requests that did not get exactly the tokens they asked for, or
-        got an id outside the vocabulary."""
-        v = self.cfg.vocab
-        return sum(len(r.generated) != r.max_new_tokens
-                   or not all(0 <= t < v for t in r.generated)
-                   for r in rec["requests"])
+        got an id outside the published vocabulary (a padding row), first
+        token included.  A request past the rows of the batch's logits got
+        no first token."""
+        first = rec["first_tokens"]
+        return sum(i >= len(first) or len(r.generated) != r.max_new_tokens
+                   or not all(0 <= t < self.vocab
+                              for t in [first[i], *r.generated])
+                   for i, r in enumerate(rec["requests"]))
 
     def _sample(self, records: list[dict]):
         """``check_requests`` finished requests drawn from the seed, the
@@ -227,9 +239,10 @@ class Driver:
         ``control`` the tokens compared are those that the reference
         computed in the control's precision puts first, at the same
         positions of the same prompts and served tokens, and the program's
-        own gap is kept beside them as ``program_gap``.  The program's
-        weights are freed first; the reference makes its own from the
-        seed."""
+        own gap is kept beside them as ``program_gap``.  A served id
+        outside the published vocabulary makes the gap infinite.  The
+        program's weights are freed first; the reference makes its own from
+        the seed."""
         sample = self._sample(records)
         if not sample:
             return {"widest_gap": float("inf"), "served_tokens": 0}

@@ -45,7 +45,12 @@ def linear(x, w, precision: str):
 
 def widest_gap(logits: np.ndarray, tokens: np.ndarray) -> float:
     """Largest amount by which a chosen token's logit lies below the best
-    logit at its position.  logits [N, V] from the reference, tokens [N]."""
+    logit at its position.  logits [N, V] from the reference, tokens [N].
+    A token outside ``[0, V)`` is no token of the vocabulary: its gap is
+    infinite."""
     logits = np.asarray(logits, np.float64)
-    chosen = np.take_along_axis(logits, np.asarray(tokens)[:, None], 1)[:, 0]
+    tokens = np.asarray(tokens)
+    if ((tokens < 0) | (tokens >= logits.shape[1])).any():
+        return float("inf")
+    chosen = np.take_along_axis(logits, tokens[:, None], 1)[:, 0]
     return float((logits.max(1) - chosen).max())

@@ -45,6 +45,7 @@ T_START = time.monotonic()
 import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -92,6 +93,21 @@ def resolve(workload: str) -> dict:
             "cost": BENCH / "cost" / f"{config['reference']}.py"}
 
 
+def program_config(config: dict, smoke: bool = False):
+    """The program's configuration, checked field by field against the
+    configuration file's ``program_fields``.  A field the program lacks
+    reads ``None`` and departs like any other."""
+    from repro.configs import get_config, get_smoke_config
+    name = config["program"]
+    cfg = get_smoke_config(name) if smoke else get_config(name)
+    wrong = {k: (getattr(cfg, k, None), v)
+             for k, v in config["program_fields"].items()
+             if getattr(cfg, k, None) != v}
+    if wrong:
+        raise Failure(f"{name}: the program runs (program, file) {wrong}")
+    return cfg
+
+
 class Context:
     """What a driver and the metric readers get."""
 
@@ -111,17 +127,7 @@ class Context:
         return jax.random.PRNGKey(int(word[0]))
 
     def program_config(self):
-        """The program's configuration, checked field by field against the
-        configuration file."""
-        from repro.configs import get_config, get_smoke_config
-        name = self.config["program"]
-        cfg = get_smoke_config(name) if self.smoke else get_config(name)
-        wrong = {k: (getattr(cfg, k), v)
-                 for k, v in self.config["program_fields"].items()
-                 if getattr(cfg, k) != v}
-        if wrong:
-            raise Failure(f"{name}: the program runs (program, file) {wrong}")
-        return cfg
+        return program_config(self.config, self.smoke)
 
 
 def _device_info(devices) -> dict:
@@ -187,7 +193,9 @@ def _compile_counter():
 
 def configure_jax():
     """Puts the program on the path and keeps JAX's compilation cache and
-    the TPU runtime's logs inside the checkout; returns ``jax``."""
+    the TPU runtime's logs inside the checkout; returns ``jax``.  The cache
+    has a directory per platform, so that programs compiled by CPU runs of
+    the harness's tests never reach a run on the chip."""
     for p in (str(ROOT / "src"), str(BENCH)):
         if p not in sys.path:
             sys.path.insert(0, p)
@@ -195,7 +203,10 @@ def configure_jax():
     logs.mkdir(parents=True, exist_ok=True)
     os.environ.setdefault("TPU_LOG_DIR", str(logs))
     import jax
-    jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
+    # the backend starts here; the cache opens at the first compile
+    platform = jax.devices()[0].platform
+    jax.config.update("jax_compilation_cache_dir",
+                      str(ROOT / ".chipbench" / "jax_cache" / platform))
     # every program, however quick to compile, is read back in later runs
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -305,7 +316,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
               "metrics": metrics, "device": info}
     if breakdown is not None:
         result["breakdown"] = breakdown
-    result["checks"] = {n: {"value": v, "limit": lim}
+    # strict JSON has no infinity: a gap that is no number prints as "inf"
+    result["checks"] = {n: {"value": v if math.isfinite(v) else str(v),
+                            "limit": lim}
                         for n, v, lim in compared}
     log(f"served tokens compared {checked['served_tokens']}"
         + (f"; the program's own gap {checked['program_gap']}" if control

@@ -40,8 +40,9 @@ def test_reference_matches_program_forward(name):
     assert jax.tree.map(lambda a: (a.shape, a.dtype), w) == \
         jax.tree.map(lambda a: (a.shape, a.dtype), shapes)
     b, t = 2, 40
-    toks = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab, (b, t)),
-                       jnp.int32)
+    # prompts from the published vocabulary, never the table's padding rows
+    toks = jnp.asarray(np.random.default_rng(0).integers(
+        0, hp["vocab_size"], (b, t)), jnp.int32)
     w32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
     with jax.default_matmul_precision("highest"):
         prog = registry.forward(reparametrize(w32, input_scale(cfg)), cfg,
@@ -60,8 +61,8 @@ def test_positions_pick_rows(name):
     """``positions`` selects the logits of those positions, row by row."""
     ref, hp, cfg, rows = _setup(name)
     w = jax.jit(lambda k: ref.init_weights(k, hp, rows))(jax.random.PRNGKey(1))
-    toks = jnp.asarray(np.random.default_rng(1).integers(0, cfg.vocab, (2, 12)),
-                       jnp.int32)
+    toks = jnp.asarray(np.random.default_rng(1).integers(
+        0, hp["vocab_size"], (2, 12)), jnp.int32)
     f = ref.make_logits_at(hp)
     full = f(w, toks, jnp.broadcast_to(jnp.arange(12), (2, 12)))
     some = f(w, toks, jnp.asarray([[3, 11], [0, 5]], jnp.int32))

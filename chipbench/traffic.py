@@ -13,7 +13,9 @@ inputs and not the amount of work:
   in one batch would be served wrong;
 - new tokens: the requests of a batch ask for ``requests_per_batch``
   values spread evenly over ``new_tokens`` (both ends included), shuffled;
-- prompt token ids: uniform over the vocabulary.
+- prompt token ids: uniform over the vocabulary it is given, the published
+  one (the configuration's ``vocab_size``), never the padding rows of a
+  program's table.
 """
 
 from __future__ import annotations
