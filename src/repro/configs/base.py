@@ -22,7 +22,10 @@ class ModelConfig:
     n_heads: int
     n_kv_heads: int
     d_ff: int
-    vocab: int
+    vocab: int                           # rows of the embedding table
+    #: the published vocabulary, where the table pads it with rows that are
+    #: no token (mamba2: 50277 ids in 50280 rows); None means ``vocab``
+    vocab_size: int | None = None
     head_dim: int | None = None          # default d_model // n_heads
     qk_norm: bool = False
     act: Literal["swiglu", "geglu", "gelu"] = "swiglu"
@@ -54,6 +57,10 @@ class ModelConfig:
     tie_embeddings: bool = True
     max_seq_len: int = 131_072
     norm_eps: float = 1e-6
+    # residual stream carried in float32 between layers, each layer's
+    # normed input cast back to the weights' dtype (mamba_ssm's
+    # ``residual_in_fp32``; the ssm family only)
+    residual_in_fp32: bool = False
     attn_q_block: int = 512              # q-block size for scanned attention
     # windowed ring-buffer KV cache for sliding-window local layers —
     # full-context cache only on global layers (gemma3: 52 of 62 layers
@@ -121,6 +128,9 @@ def reduce_for_smoke(cfg: ModelConfig, **overrides) -> ModelConfig:
         vocab=min(cfg.vocab, 512),
         max_seq_len=1024,
     )
+    if cfg.vocab_size is not None:
+        # the table keeps as many padding rows as the published config
+        changes["vocab_size"] = changes["vocab"] - (cfg.vocab - cfg.vocab_size)
     if cfg.n_experts:
         changes["n_experts"] = min(cfg.n_experts, 4)
         changes["top_k"] = min(cfg.top_k, 2)

@@ -5,9 +5,10 @@ from repro.configs.base import ModelConfig, reduce_for_smoke
 CONFIG = ModelConfig(
     name="mamba2-2.7b", family="ssm",
     n_layers=64, d_model=2560, n_heads=0, n_kv_heads=0,
-    d_ff=0, vocab=50280, head_dim=64,
+    d_ff=0, vocab=50280, vocab_size=50277, head_dim=64,
     ssm_state=128, ssm_heads=80, ssm_expand=2, ssm_chunk=256, conv_width=4,
     tie_embeddings=True, max_seq_len=1_048_576,
+    norm_eps=1e-5, residual_in_fp32=True,
     source="arXiv:2405.21060 (Mamba-2)")
 
 def smoke() -> ModelConfig:

@@ -84,6 +84,13 @@ def unembed(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
              else params["unembed"])
     logits = jnp.einsum("bsd,dv->bsv", x, table.astype(x.dtype))
     logits = constrain(logits, ("batch", "seq", "vocab"))
+    if cfg.vocab_size is not None and cfg.vocab_size < cfg.vocab:
+        # rows past the published vocabulary pad the table and are no
+        # token: they never win an argmax.  Iota-compare masking keeps the
+        # op elementwise along the sharded vocab axis
+        ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        logits = jnp.where(ids >= cfg.vocab_size,
+                           jnp.finfo(logits.dtype).min, logits)
     return logits
 
 

@@ -183,12 +183,15 @@ def ssm_decode_step(params: dict, x: jax.Array, cache_conv, cache_state,
     dt1 = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
                           + params["dt_bias"].astype(jnp.float32))  # [B,H]
     a = -jnp.exp(params["A_log"].astype(jnp.float32))
-    decay = jnp.exp(dt1 * a)                            # [B,H]
-    outer = jnp.einsum("bhp,bn->bhpn", dt1[..., None] * xh,
-                       b_ssm.astype(jnp.float32))
-    state = cache_state * decay[..., None, None] + outer
-    y = jnp.einsum("bhpn,bn->bhp", state, c_ssm.astype(jnp.float32))
-    y = y + params["D"].astype(jnp.float32)[None, :, None] * xh
+    # the ops that read and write the recurrent state carry this scope in
+    # their HLO metadata, for the device trace (chipbench's ssm.state_ms)
+    with jax.named_scope("repro.ssm.state"):
+        decay = jnp.exp(dt1 * a)                        # [B,H]
+        outer = jnp.einsum("bhp,bn->bhpn", dt1[..., None] * xh,
+                           b_ssm.astype(jnp.float32))
+        state = cache_state * decay[..., None, None] + outer
+        y = jnp.einsum("bhpn,bn->bhp", state, c_ssm.astype(jnp.float32))
+        y = y + params["D"].astype(jnp.float32)[None, :, None] * xh
     y = y.reshape(b_, 1, d_inner).astype(x.dtype)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     y = rmsnorm(y, params["norm"], cfg.norm_eps)

@@ -119,6 +119,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     (VLM patches) overriding the first V positions."""
     b_, s = tokens.shape
     x = embed_tokens(params, tokens, cfg)
+    dtype = x.dtype
     if extra_embeddings is not None:
         v = extra_embeddings.shape[1]
         x = jnp.concatenate([extra_embeddings.astype(x.dtype), x[:, v:]],
@@ -132,9 +133,9 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
         @remat_layer
         def ssm_body(h, lp):
             return (h + ssm_lib.ssm_forward(
-                lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg), None)
+                lp, _ssm_input(h, lp, cfg, dtype), cfg), None)
 
-        x, _ = jax.lax.scan(ssm_body, x, params["layers"])
+        x, _ = jax.lax.scan(ssm_body, _residual(x, cfg), params["layers"])
         aux = jnp.zeros((), jnp.float32)
     elif cfg.n_experts and cfg.moe_every > 1:
         x, aux = _forward_interleaved_moe(params, cfg, x, positions, windows)
@@ -163,9 +164,22 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
 
     if last_only:
         x = x[:, -1:]
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
     logits = unembed(params, x, cfg)
     return DecoderOutput(logits=logits, aux_loss=aux)
+
+
+def _residual(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The residual stream: float32 with ``residual_in_fp32``, else the
+    embedding's dtype."""
+    return x.astype(jnp.float32) if cfg.residual_in_fp32 else x
+
+
+def _ssm_input(h: jax.Array, lp: dict, cfg: ModelConfig, dtype) -> jax.Array:
+    """A layer's normed input in the weights' dtype: mamba_ssm's fused
+    add+norm, which normalises the float32 residual and hands the mixer its
+    matmuls' dtype."""
+    return rmsnorm(h, lp["norm1"], cfg.norm_eps).astype(dtype)
 
 
 def _forward_interleaved_moe(params, cfg, x, positions, windows):
@@ -264,6 +278,7 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     """token: [B,1] int32; index: scalar int32 position.  Returns
     (logits [B,1,V], updated caches)."""
     x = embed_tokens(params, token, cfg)
+    dtype = x.dtype
     windows = layer_pattern(cfg)
     is_chunked = chunked_flags(cfg)
 
@@ -272,13 +287,12 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
             h = carry
             lp, conv_c, state_c = xs
             out, conv_c, state_c = ssm_lib.ssm_decode_step(
-                lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), conv_c, state_c,
-                cfg)
+                lp, _ssm_input(h, lp, cfg, dtype), conv_c, state_c, cfg)
             return h + out, (conv_c, state_c)
 
         x, (conv_cs, state_cs) = jax.lax.scan(
-            body, x, (params["layers"], caches["ssm"]["conv"],
-                      caches["ssm"]["state"]))
+            body, _residual(x, cfg), (params["layers"], caches["ssm"]["conv"],
+                                      caches["ssm"]["state"]))
         caches = {"ssm": {"conv": conv_cs, "state": state_cs}}
     elif "k_q" in caches:
         def body_q(carry, xs):
@@ -333,7 +347,7 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
         caches = {"k": upd(caches["k"], k_new, index, axis=2),
                   "v": upd(caches["v"], v_new, index, axis=2)}
 
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
     logits = unembed(params, x, cfg)
     return logits, caches
 

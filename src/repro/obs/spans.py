@@ -19,8 +19,11 @@ device down to the innermost span covering it):
   return or early restart.  The idle table.
 - ``repro.serve.restart`` (``from_gb``, ``to_gb``): the restart target
   chosen and logged.  The idle table.
-- ``repro.engine.setup``: caches made, prompts sent to the device.  The
-  idle table.
+- ``repro.engine.setup`` (``cache_kind``, ``cache_bytes``): caches made,
+  prompts sent to the device.  ``cache_kind`` is ``"ssm"`` for an
+  attention-free model's recurrent state and ``"kv"`` for a model with a
+  KV cache; ``cache_bytes`` is the caches' size on the device.  The idle
+  table.
 - ``repro.engine.load``: a new engine's first step: trace, lower, compile
   or load, dispatch.  The idle table.
 - ``repro.engine.replay`` (``positions``): the prompt replayed into the
@@ -31,6 +34,13 @@ device down to the innermost span covering it):
   ``engine.host_step_ms``.
 - ``repro.engine.predictor``: one decode step's memory bookkeeping,
   predictor and restart decision.  ``predictor.host_ms``.
+
+Device scopes (``jax.named_scope``: the HLO metadata of the ops inside,
+so the device trace's ops carry them):
+
+- ``repro.ssm.state`` (``models/ssm.ssm_decode_step``): the decode step's
+  recurrent-state update (decay, outer product, add), its read-out and the
+  ``D`` skip.  ``ssm.state_ms`` and ``ssm.state_roofline``.
 
 Counters:
 

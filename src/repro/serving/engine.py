@@ -100,8 +100,11 @@ class ServeEngine:
         self._reset_run_state()
         b = len(requests)
         prompt_len = max(len(r.prompt) for r in requests)
-        with span("engine.setup"):
+        with span("engine.setup") as mark:
             caches = registry.init_caches(cfg, b, ecfg.max_context)
+            mark.set_metadata(
+                cache_kind="ssm" if cfg.is_attention_free else "kv",
+                cache_bytes=pytree_nbytes(caches))
             # prefill (teacher-forced forward over the padded prompt batch)
             toks = np.zeros((b, prompt_len), np.int32)
             for i, r in enumerate(requests):
