@@ -9,10 +9,12 @@ One chip (the default).  qwen3-1.7b at its published width (28L, d=2048,
 serves 8 requests of 128 prompt tokens and 32 new tokens through
 ``ServeEngine`` and the early-restart loop of ``repro.launch.serve``.  It
 starts on a slice smaller than its weights, so the predictor restarts it
-once onto the chip's 1x1 slice.  Then its decode-replay logits at the last
-prompt position are checked against the XLA forward (``registry.prefill``),
-and the Pallas flash-attention forward, which must lower to a TPU kernel,
-against the XLA forward.
+once onto the chip's 1x1 slice.  Then the logits at the last prompt
+position that the engine's cache-writing prefill returned, and those of
+the prompt replayed one position per decode step (``registry.decode_step``),
+are checked against the XLA forward (``registry.prefill``), and the Pallas
+flash-attention forward, which must lower to a TPU kernel, against the XLA
+forward.
 
 Four chips (``--chips 4``).  Only the multi-tenant pod: three qwen3-1.7b
 tenants lease 1x1 slices of the host's 2x2 buddy pod with their params and
@@ -137,8 +139,16 @@ def serve_phase(args, device: str) -> None:
     batch = {"tokens": jnp.asarray(np.stack([r.prompt for r in reqs]))}
     prefill = jax.jit(registry.prefill, static_argnums=1)
     ref = prefill(params, cfg, batch)[:, -1, :cfg.vocab]
-    check_logits("decode replay vs XLA prefill, last prompt position",
+    check_logits("engine prefill vs XLA forward, last prompt position",
                  res.engine.prompt_logits[:, -1, :cfg.vocab], ref, device)
+    step = jax.jit(lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c),
+                   donate_argnums=(3,))
+    caches = registry.init_caches(cfg, N_REQUESTS, MAX_CONTEXT)
+    for pos in range(PROMPT_LEN):
+        replayed, caches = step(params, batch["tokens"][:, pos:pos + 1],
+                                jnp.int32(pos), caches)
+    check_logits("decode step replay vs XLA forward, last prompt position",
+                 replayed[:, -1, :cfg.vocab], ref, device)
     lowered = prefill.lower(params, dataclasses.replace(cfg,
                                                         attn_impl="pallas"),
                             batch)

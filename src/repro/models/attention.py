@@ -121,9 +121,31 @@ def mha_full(params: dict, x: jax.Array, cfg: ModelConfig,
              positions: jax.Array, window=None, chunk=None,
              causal: bool = True, q_block: int | None = None) -> jax.Array:
     """Full-sequence self attention (training / prefill)."""
-    q_block = q_block or cfg.attn_q_block
     q, k, v = _project_qkv(params, x, cfg, positions, rope=not _no_rope(cfg))
-    s = x.shape[1]
+    return _self_attend(params, q, k, v, cfg, positions, window, chunk,
+                        causal, q_block)
+
+
+def mha_prefill(params: dict, x: jax.Array, cfg: ModelConfig,
+                positions: jax.Array, cache_dtype):
+    """Causal self attention over a whole prompt, with no window or chunk,
+    that also returns its keys and values for the decode cache.
+
+    x:[B,S,d].  Returns (y, k, v), k/v [B,S,KH,hd] in ``cache_dtype``, for
+    the caller to write at positions ``0..S-1``: the qk-norm, RoPE and
+    rounding of :func:`mha_decode_append`, and the attention reads them as
+    the decode steps after it will, from the cache's dtype."""
+    q, k, v = _project_qkv(params, x, cfg, positions, rope=not _no_rope(cfg))
+    k, v = k.astype(cache_dtype), v.astype(cache_dtype)
+    y = _self_attend(params, q, k.astype(q.dtype), v.astype(q.dtype), cfg,
+                     positions)
+    return y, k, v
+
+
+def _self_attend(params, q, k, v, cfg: ModelConfig, positions, window=None,
+                 chunk=None, causal: bool = True, q_block: int | None = None):
+    q_block = q_block or cfg.attn_q_block
+    s = q.shape[1]
     pos = positions[0] if positions.ndim > 1 else positions
     static_window = isinstance(window, int) or window is None
     if (cfg.attn_impl == "pallas" and chunk is None and causal

@@ -64,7 +64,7 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     @remat_layer
     def mamba_block(h, lp):
         return h + ssm_lib.ssm_forward(
-            lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg), None
+            lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg)[0], None
 
     @remat_layer
     def group_body(h, lp_group):

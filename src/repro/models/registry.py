@@ -102,6 +102,25 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     return transformer.decode_step(params, cfg, token, index, caches)
 
 
+def prefill_length(cfg: ModelConfig, caches: dict, s: int) -> int | None:
+    """The positions one :func:`prefill_caches` call takes for an
+    ``s``-position prompt into ``caches``, the layout :func:`init_caches`
+    made, or None where the prompt is replayed one :func:`decode_step` per
+    position (:func:`repro.models.transformer.prefill_length`)."""
+    return transformer.prefill_length(cfg, caches, s)
+
+
+def prefill_caches(params: dict, cfg: ModelConfig, tokens: jax.Array,
+                   length: jax.Array, caches: dict
+                   ) -> tuple[jax.Array, dict]:
+    """The prompt in one call.  tokens: [B, :func:`prefill_length`] int32,
+    the prompt's ``length`` positions then pads.  Returns (logits [B,1,V]
+    at position ``length - 1``, caches with the prompt written)."""
+    out = transformer.forward(params, cfg, tokens, last_only=True,
+                              caches=caches, length=length)
+    return out.logits, out.caches
+
+
 def supports_long_context(cfg: ModelConfig) -> bool:
     return cfg.has_subquadratic_attention
 

@@ -125,30 +125,48 @@ def ssd_chunked(x, dt, a, B_in, C_in, chunk: int, state0=None,
 
 
 def ssm_forward(params: dict, x: jax.Array, cfg: ModelConfig
-                ) -> jax.Array:
-    """Full-sequence Mamba2 mixer (training / prefill)."""
+                ) -> tuple[jax.Array, jax.Array, jax.Array | None]:
+    """Full-sequence Mamba2 mixer (training / prefill).  x:[B,S,d].
+
+    Returns (y [B,S,d]; the convolution's input [B,S,ch], whose last rows
+    :func:`conv_cache` keeps for decoding; the SSD's final state
+    [B,H,P,N] float32, None on the Pallas path).  The convolution and the
+    ``D`` skip run in float32 and round after, as :func:`ssm_decode_step`
+    runs them, so that a prefill leaves what decoding the prompt token by
+    token leaves."""
     d_inner, h, p, n = ssm_dims(cfg)
     b_, s, _ = x.shape
-    z, xbc, dt = _split_proj(params, x, cfg)
-    xbc = _causal_conv(xbc, params, cfg)
+    f32 = jnp.float32
+    z, xbc_in, dt = _split_proj(params, x, cfg)
+    xbc = _causal_conv(xbc_in.astype(f32), params, cfg).astype(x.dtype)
     x_ssm, b_ssm, c_ssm = jnp.split(xbc, [d_inner, d_inner + n], axis=-1)
     x_heads = x_ssm.reshape(b_, s, h, p)
     x_heads = constrain(x_heads, ("batch", "seq", "ssm_inner", None))
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + params["dt_bias"].astype(jnp.float32))
-    a = -jnp.exp(params["A_log"].astype(jnp.float32))
+    dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"].astype(f32))
+    a = -jnp.exp(params["A_log"].astype(f32))
     if cfg.ssm_impl == "pallas":
         from repro.kernels.ops import ssd_mixer
-        y = ssd_mixer(x_heads, dt, a, b_ssm.astype(jnp.float32),
-                      c_ssm.astype(jnp.float32), chunk=cfg.ssm_chunk)
+        y = ssd_mixer(x_heads, dt, a, b_ssm.astype(f32), c_ssm.astype(f32),
+                      chunk=cfg.ssm_chunk)
+        state = None
     else:
-        y, _ = ssd_chunked(x_heads, dt, a, b_ssm, c_ssm, cfg.ssm_chunk)
-    y = y + params["D"].astype(y.dtype)[None, None, :, None] * x_heads
-    y = y.reshape(b_, s, d_inner)
-    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+        y, state = ssd_chunked(x_heads.astype(f32), dt, a, b_ssm, c_ssm,
+                               cfg.ssm_chunk)
+    y = y.astype(f32) + params["D"].astype(f32)[None, None, :, None] \
+        * x_heads.astype(f32)
+    y = y.reshape(b_, s, d_inner).astype(x.dtype)
+    y = y * jax.nn.silu(z.astype(f32)).astype(y.dtype)
     y = rmsnorm(y, params["norm"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"])
-    return constrain(out, ("batch", "seq", None))
+    return constrain(out, ("batch", "seq", None)), xbc_in, state
+
+
+def conv_cache(xbc: jax.Array, cfg: ModelConfig, dtype) -> jax.Array:
+    """The decode step's conv cache [B,W-1,ch] after the convolution's
+    input rows ``xbc`` [B,S,ch]: the last ``conv_width - 1`` of them, zeros
+    before the first, in ``dtype``: what the decode steps' window leaves."""
+    w = cfg.conv_width - 1
+    return jnp.pad(xbc, ((0, 0), (w, 0), (0, 0)))[:, -w:].astype(dtype)
 
 
 # -- recurrent decode ----------------------------------------------------------

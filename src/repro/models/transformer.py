@@ -75,6 +75,8 @@ def remat_layer(fn):
 class DecoderOutput:
     logits: jax.Array
     aux_loss: jax.Array
+    #: the decode caches a prefill wrote (:func:`forward`'s ``caches``)
+    caches: dict | None = None
 
 
 # -- init ---------------------------------------------------------------------------
@@ -114,9 +116,18 @@ def init_rmsnorm_stacked(b: ParamBuilder, name: str, dim: int, L: int):
 
 def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
             extra_embeddings: jax.Array | None = None,
-            last_only: bool = False) -> DecoderOutput:
+            last_only: bool = False, caches: dict | None = None,
+            length: jax.Array | None = None) -> DecoderOutput:
     """tokens: [B,S] int32. extra_embeddings: [B,V,d] stub frontend output
-    (VLM patches) overriding the first V positions."""
+    (VLM patches) overriding the first V positions.
+
+    With ``caches``, as :func:`init_caches` made them for a layout
+    :func:`prefill_length` accepts, this is the serving prefill:
+    ``DecoderOutput.caches`` holds them with the prompt's positions
+    written.  ``length`` (a scalar) is where the prompt ends in ``tokens``:
+    ``last_only`` takes the logits at ``length - 1``, and the keys and
+    values of the pads after it are written as zeros (an SSM takes no
+    pads)."""
     b_, s = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     dtype = x.dtype
@@ -132,10 +143,19 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     if cfg.family == "ssm":
         @remat_layer
         def ssm_body(h, lp):
-            return (h + ssm_lib.ssm_forward(
-                lp, _ssm_input(h, lp, cfg, dtype), cfg), None)
+            out, xbc, state = ssm_lib.ssm_forward(
+                lp, _ssm_input(h, lp, cfg, dtype), cfg)
+            if caches is None:
+                return h + out, None
+            c = caches["ssm"]
+            return h + out, {
+                "conv": ssm_lib.conv_cache(xbc, cfg, c["conv"].dtype),
+                "state": state.astype(c["state"].dtype)}
 
-        x, _ = jax.lax.scan(ssm_body, _residual(x, cfg), params["layers"])
+        x, ssm_caches = jax.lax.scan(ssm_body, _residual(x, cfg),
+                                     params["layers"])
+        if caches is not None:
+            caches = {"ssm": ssm_caches}
         aux = jnp.zeros((), jnp.float32)
     elif cfg.n_experts and cfg.moe_every > 1:
         x, aux = _forward_interleaved_moe(params, cfg, x, positions, windows)
@@ -147,8 +167,17 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
             window = jnp.where(win >= GLOBAL, jnp.int32(2 ** 30), win)
             chunk = window if is_chunked else None
             w_arg = None if no_window else window
-            h = h + attn.mha_full(lp, rmsnorm(h, lp["norm1"], cfg.norm_eps),
-                                  cfg, positions, window=w_arg, chunk=chunk)
+            hn = rmsnorm(h, lp["norm1"], cfg.norm_eps)
+            if caches is None:
+                h = h + attn.mha_full(lp, hn, cfg, positions, window=w_arg,
+                                      chunk=chunk)
+                kv = None
+            else:           # every layer global: prefill_length
+                out, k, v = attn.mha_prefill(lp, hn, cfg, positions,
+                                             caches["k"].dtype)
+                h = h + out
+                pad = (positions >= length)[..., None, None]
+                kv = (jnp.where(pad, 0, k), jnp.where(pad, 0, v))
             hn = rmsnorm(h, lp["norm2"], cfg.norm_eps)
             if cfg.n_experts:
                 out, a = moe_lib.moe_layer(lp, hn, cfg)
@@ -156,17 +185,25 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
             else:
                 out = mlp(lp, hn, cfg)
             h = h + out
-            return (h, aux), None
+            return (h, aux), kv
 
-        (x, aux), _ = jax.lax.scan(
+        (x, aux), kv = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)),
             (params["layers"], windows))
+        if caches is not None:
+            # [L, B, S, KH, hd] each, into the donated stacks at position 0;
+            # positions past the cache are pads
+            c = caches["k"].shape[2]
+            upd = jax.lax.dynamic_update_slice_in_dim
+            caches = {name: upd(caches[name], new[:, :, :c], 0, axis=2)
+                      for name, new in zip(("k", "v"), kv)}
 
     if last_only:
-        x = x[:, -1:]
+        x = x[:, -1:] if length is None else \
+            jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
     logits = unembed(params, x, cfg)
-    return DecoderOutput(logits=logits, aux_loss=aux)
+    return DecoderOutput(logits=logits, aux_loss=aux, caches=caches)
 
 
 def _residual(x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -350,6 +387,30 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
     logits = unembed(params, x, cfg)
     return logits, caches
+
+
+def prefill_length(cfg: ModelConfig, caches: dict, s: int) -> int | None:
+    """The positions one prefill call (:func:`forward` with ``caches``)
+    takes for an ``s``-position prompt, or None where the prompt is
+    replayed through :func:`decode_step` one position at a time.
+
+    The prefill writes two layouts, as :func:`init_caches` made them: an
+    SSM's ``{"ssm": {"conv", "state"}}`` off the Pallas path, which returns
+    no state, taken at ``s`` positions since a pad would enter the state;
+    and ``{"k", "v"}`` behind the plain layer scan of a dense decoder
+    whose every layer attends globally, padded up to a multiple of 128
+    positions, or of ``attn_q_block`` past it, where attention scans query
+    blocks instead of holding the whole [S, S] scores: a few programs
+    serve every prompt length.  Expert routing, windows, chunks and patch
+    embeddings keep the replay."""
+    if set(caches) == {"ssm"}:
+        ok = cfg.family == "ssm" and cfg.ssm_impl != "pallas"
+        return s if ok else None
+    if not (set(caches) == {"k", "v"} and cfg.family == "dense"
+            and not cfg.n_experts and all_layers_global(cfg)):
+        return None
+    step = cfg.attn_q_block if s > cfg.attn_q_block else 128
+    return -(-s // step) * step
 
 
 def _decode_interleaved_moe(params, cfg, x, index, caches, windows):

@@ -24,10 +24,15 @@ device down to the innermost span covering it):
   attention-free model's recurrent state and ``"kv"`` for a model with a
   KV cache; ``cache_bytes`` is the caches' size on the device.  The idle
   table.
-- ``repro.engine.load``: a new engine's first step: trace, lower, compile
-  or load, dispatch.  The idle table.
-- ``repro.engine.replay`` (``positions``): the prompt replayed into the
-  caches.  The idle table.
+- ``repro.engine.load`` (``program``): an engine's first call of one of
+  its programs, ``"prefill"`` or ``"decode"``: trace, lower, compile or
+  load, dispatch.  Both lie in ``repro.engine.replay``: after a prefill
+  the decode step is compiled there, ahead of its first call.  The idle
+  table.
+- ``repro.engine.replay`` (``positions``, ``calls``): the prompt's
+  ``positions`` written into the caches, by ``calls`` program calls: 1
+  where one prefill call writes the cache layout, ``positions`` where the
+  prompt is replayed through the decode step.  The idle table.
 - ``repro.engine.decode`` (``steps``): the decode loop.
   ``engine.host_step_ms``, with its ``sync`` children.
 - ``repro.engine.sync``: one decode step's wait for its token on the host.

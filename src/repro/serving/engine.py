@@ -78,11 +78,20 @@ class ServeEngine:
 
         def decode_step(p, t, i, c):
             return registry.decode_step(p, cfg, t, i, c)
+
+        def prefill(p, t, n, c):
+            return registry.prefill_caches(p, cfg, t, n, c)
         # the caches are donated: each step writes them in place.  Without
         # it every step queued ahead of the device holds a fresh copy of
         # the whole cache, and the queue fills the chip's HBM.
         self._decode = jax.jit(decode_step, donate_argnums=(3,))
-        self._loaded = False
+        # an SSM's prefill writes its caches without reading them: kept as
+        # arguments, the donated buffers take the new caches in place
+        self._prefill = jax.jit(prefill, donate_argnums=(3,),
+                                keep_unused=True)
+        #: the programs this engine has called once: ``"decode"``,
+        #: ``"prefill"``
+        self._loaded: set[str] = set()
 
     def _reset_run_state(self) -> None:
         """Fresh per-run accounting: a second batch on the same engine must
@@ -105,8 +114,10 @@ class ServeEngine:
             mark.set_metadata(
                 cache_kind="ssm" if cfg.is_attention_free else "kv",
                 cache_bytes=pytree_nbytes(caches))
-            # prefill (teacher-forced forward over the padded prompt batch)
-            toks = np.zeros((b, prompt_len), np.int32)
+            # the padded prompt batch, and the pads after it that the
+            # prefill program for this layout takes
+            length = registry.prefill_length(cfg, caches, prompt_len)
+            toks = np.zeros((b, length or prompt_len), np.int32)
             for i, r in enumerate(requests):
                 toks[i, :len(r.prompt)] = r.prompt
             batch = {"tokens": jnp.asarray(toks)}
@@ -115,25 +126,42 @@ class ServeEngine:
                                             jnp.bfloat16)
                 caches = registry.prefill_encoder(self.params, cfg, batch,
                                                   caches)
-        # replay the prompt through decode_step to fill the KV cache.  The
-        # step is dispatched here and in the decode loop, not through a
-        # helper method: called from one, its first call lowered 3.5 times
-        # slower on a TPU v5e host (0.31-0.36 s against 0.09 s), every engine
-        logits = None
-        with span("engine.replay", positions=prompt_len):
-            for pos in range(prompt_len):
-                with self._load_span():
-                    logits, caches = self._decode(
-                        self.params, batch["tokens"][:, pos:pos + 1],
-                        jnp.int32(pos), caches)
+        # fill the caches: one prefill call where the model writes this
+        # cache layout, else the prompt replayed through decode_step, one
+        # call per position.  The programs are dispatched here and in the
+        # decode loop, not through a helper method: called from one, the
+        # step's first call lowered 3.5 times slower on a TPU v5e host
+        # (0.31-0.36 s against 0.09 s), every engine
+        with span("engine.replay", positions=prompt_len,
+                  calls=prompt_len if length is None else 1):
+            if length is None:
+                for pos in range(prompt_len):
+                    with self._load_span("decode"):
+                        logits, caches = self._decode(
+                            self.params, batch["tokens"][:, pos:pos + 1],
+                            jnp.int32(pos), caches)
+            else:
+                with self._load_span("prefill"):
+                    logits, caches = self._prefill(
+                        self.params, batch["tokens"], jnp.int32(prompt_len),
+                        caches)
             #: logits at the last prompt position, kept for reference checks
             self.prompt_logits = logits
+            next_tok = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1)[:, None]
+            if "decode" not in self._loaded:
+                # after a prefill the decode step compiles (or loads) here,
+                # while the device runs the prefill, and its first call in
+                # the decode loop finds it ready
+                with self._load_span("decode"):
+                    self._decode.lower(self.params,
+                                       next_tok.astype(jnp.int32),
+                                       jnp.int32(prompt_len), caches
+                                       ).compile()
             self._note_iteration(caches, prompt_len)
 
         steps = min(max(r.max_new_tokens for r in requests),
                     ecfg.max_context - prompt_len)
         with span("engine.decode", steps=steps):
-            next_tok = jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1)[:, None]
             for step in range(steps):
                 pos = prompt_len + step
                 logits, caches = self._decode(self.params,
@@ -152,13 +180,14 @@ class ServeEngine:
                 self._check_memory(caches, pos)
         return requests
 
-    def _load_span(self):
-        """``repro.engine.load`` for a new engine's first step, which traces,
-        lowers and compiles (or loads) it; a no-op for the steps after."""
-        if self._loaded:
+    def _load_span(self, program: str):
+        """``repro.engine.load`` for this engine's first call of
+        ``program``, which traces, lowers and compiles (or loads) it; a
+        no-op for the calls after."""
+        if program in self._loaded:
             return _LOADED
-        self._loaded = True
-        return span("engine.load")
+        self._loaded.add(program)
+        return span("engine.load", program=program)
 
     # -- instrumentation (paper §3.2.2) --------------------------------------------
 

@@ -3,9 +3,10 @@
 The TPU compiler refuses what interpret mode accepts: blocks off the (8, 128)
 tiling, ops Mosaic cannot lower, programs that overflow HBM.  These tests
 compile the Pallas kernels (``interpret=False``) at the widths of the models
-that use them, and the full-width qwen3-1.7b decode step, for a chip that is
-described and not attached: it must fit, and must write its donated caches in
-place.  Nothing runs, so they say nothing about results or times.
+that use them, and the full-width qwen3-1.7b decode step and the engine's
+prefill of qwen3-1.7b and mamba2-2.7b, for a chip that is described and not
+attached: they must fit, and must write their donated caches in place.
+Nothing runs, so they say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under several test workers only
@@ -20,10 +21,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.memory.accountant import pytree_nbytes
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models import registry
 from repro.models.ssm import ssm_dims
+from repro.serving.engine import EngineConfig, ServeEngine
 
 HBM_BYTES = 16 * 1024 ** 3
 
@@ -125,3 +128,30 @@ def test_qwen3_decode_step_writes_cache_in_place(one_chip):
     copies = [name for name, typ in ops
               if name.startswith("copy") and typ.startswith(stack)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("arch, rows, prompt, context", [
+    ("qwen3-1.7b", 32, 224, 512), ("qwen3-1.7b", 64, 112, 512),
+    ("mamba2-2.7b", 16, 112, 256), ("qwen3-1.7b", 8, 4000, 4096)])
+def test_prefill_fits_one_chip_and_fills_the_donated_caches(
+        one_chip, arch, rows, prompt, context):
+    """The engine's prefill program at the cells' longest prompts, and at
+    8 prompts of 4000 positions in a 4096-position cache: it fits one chip,
+    and the donated caches take the caches it returns in place, for the
+    SSM too, whose prefill writes its caches without reading them."""
+    cfg = get_config(arch)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = on_chip(registry.abstract_params(cfg)[0])
+    caches = on_chip(jax.eval_shape(
+        lambda: registry.init_caches(cfg, rows, context)))
+    engine = ServeEngine(cfg, params, EngineConfig(max_batch=rows,
+                                                   max_context=context))
+    length = registry.prefill_length(cfg, caches, prompt)
+    mem = engine._prefill.lower(
+        params, _sds((rows, length), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), caches).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == pytree_nbytes(caches)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES

@@ -100,13 +100,18 @@ def test_span_tree(recorded):
     assert restart[3] == {"from_gb": 1e-4,
                           "to_gb": recorded.backend.profiles[0].mem_gb}
     for a in attempts:
-        for name in ("setup", "load", "replay", "decode"):
+        for name in ("setup", "replay", "decode"):
             assert len(recorded.named(f"repro.engine.{name}", a)) == 1, name
+        # one load per program, both inside the replay: the prefill fills
+        # the caches in one call, and the decode step compiles before the
+        # decode loop calls it
         replay, = recorded.named("repro.engine.replay", a)
-        assert replay[3] == {"positions": 4}
-        assert len(recorded.named("repro.engine.load", replay)) == 1
-        setup, = recorded.named("repro.engine.setup", a)
+        assert replay[3] == {"positions": 4, "calls": 1}
+        assert [s[3] for s in recorded.named("repro.engine.load", replay)] \
+            == [{"program": "prefill"}, {"program": "decode"}]
+        assert len(recorded.named("repro.engine.load", a)) == 2
         decode, = recorded.named("repro.engine.decode", a)
+        setup, = recorded.named("repro.engine.setup", a)
         assert setup[2] <= replay[1] and replay[2] <= decode[1]
         assert decode[3] == {"steps": 12}
 
@@ -138,13 +143,20 @@ def test_token_times_stamp_each_token_inside_the_last_attempt(recorded):
 def test_compile_log_sees_each_new_engine_load_and_no_second_run(recorded):
     log = compile_log()
     loads = recorded.named("repro.engine.load")
-    # each attempt builds an engine, the second run reuses the last one
-    assert len(loads) == 2
+    # each attempt builds an engine and loads its two programs, the second
+    # run reuses the last engine's
+    assert len(loads) == 4
     traced = [(recorded.ns(end), fun) for end, event, _, fun in
               log.events(0.0, float("inf")) if event == TRACE_EVENT]
-    for _, s0, s1, _ in loads:
-        assert any(s0 <= t <= s1 and fun == "decode_step"
-                   for t, fun in traced)
+    for _, s0, s1, args in loads:
+        fun = {"prefill": "prefill", "decode": "decode_step"}[args["program"]]
+        assert any(s0 <= t <= s1 and f == fun for t, f in traced)
+    # the decode loop finds its step compiled: it lowers and compiles
+    # nothing of it
+    for _, s0, s1, _ in recorded.named("repro.engine.decode"):
+        assert not [e for e in log.events(0.0, float("inf"))
+                    if e[1] != TRACE_EVENT and e[3] == "decode_step"
+                    and s0 <= recorded.ns(e[0]) <= s1]
     second = [s for s in recorded.spans
               if s[0] in ("repro.engine.replay", "repro.engine.decode")
               and s[1] > recorded.named("repro.serve.attempt")[-1][2]]

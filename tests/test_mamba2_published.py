@@ -115,6 +115,62 @@ def test_served_logits_follow_the_float32_reference(bench, seed):
         > RMS_LIMIT
 
 
+@pytest.mark.parametrize("seed", [1, 2])
+def test_prefill_serves_what_the_replay_serves(bench, seed):
+    """At the published 64 layers the engine's one prefill call leaves the
+    last prompt position's logits and the caches within the program's own
+    bfloat16 error of the test's own replay: nearer the replay's than the
+    replay's logits lie to the float32 reference, and its logits under
+    ``RMS_LIMIT`` of that reference, as the replay's are.  It serves the
+    replay's first tokens, and the replay's generated tokens up to where
+    the replay's own logits nearly tie: a row may part from the replay only
+    at a token whose logit the replay puts less than the two paths' widest
+    logit difference below its best."""
+    hp, cfg, rows = _setup(bench, 64)
+    w = jax.jit(lambda k: bench.ref.init_weights(k, hp, rows))(
+        jax.random.PRNGKey(seed))
+    prompts = np.random.default_rng(seed).integers(
+        0, hp["vocab_size"], (B, T)).astype(np.int32)
+    seq, replayed = _decode(cfg, w, prompts)
+    engine = ServeEngine(cfg, w, EngineConfig(max_batch=B,
+                                              max_context=T + K,
+                                              predict=False))
+    reqs = [Request(uid=i, prompt=prompts[i], max_new_tokens=K - 2)
+            for i in range(B)]
+    engine.run(reqs)
+    logits = np.asarray(engine.prompt_logits[:, 0, :cfg.vocab_size],
+                        np.float32)
+
+    def rms(got, want):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        return float(np.sqrt(((got - want) ** 2).mean()
+                             / (want ** 2).mean()))
+    want = bench.ref.make_logits_at(hp)(
+        w, jnp.asarray(prompts), jnp.full((B, 1), T - 1))[:, 0]
+    own = rms(replayed[:, 0], want)
+    assert rms(logits, replayed[:, 0]) < own
+    assert rms(logits, want) < RMS_LIMIT
+    # the replay's tokens: its first token at T, then those it fed on
+    assert (logits.argmax(-1) == seq[:, T]).all()
+    rounding = np.abs(logits - replayed[:, 0]).max()
+    for row, r in enumerate(reqs):
+        parted = [j for j, tok in enumerate(r.generated)
+                  if tok != seq[row, T + 1 + j]]
+        if parted:
+            at = replayed[row, parted[0] + 1]
+            assert at.max() - at[r.generated[parted[0]]] <= rounding
+    step = jax.jit(lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c))
+    caches = registry.init_caches(cfg, B, T + K)
+    for pos in range(T):
+        _, caches = step(w, jnp.asarray(prompts[:, pos:pos + 1]),
+                         jnp.int32(pos), caches)
+    _, got = jax.jit(lambda p, t, c: registry.prefill_caches(
+        p, cfg, t, T, c))(w, jnp.asarray(prompts),
+                          registry.init_caches(cfg, B, T + K))
+    for name in ("conv", "state"):
+        assert rms(got["ssm"][name], caches["ssm"][name]) < own
+
+
 @pytest.mark.parametrize("path", ["forward", "decode_step"])
 def test_float32_program_is_the_reference(bench, path):
     """On float32 weights the residual flag changes nothing, and both the
