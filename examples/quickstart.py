@@ -52,8 +52,7 @@ def main() -> None:
               f"step {args.steps})")
 
     engine = ServeEngine(cfg, state["params"],
-                         EngineConfig(max_batch=2, max_context=64,
-                                      predict=False))
+                         EngineConfig(max_batch=2, max_context=64))
     reqs = [Request(uid=i, prompt=np.arange(4, dtype=np.int32) + i,
                     max_new_tokens=12) for i in range(2)]
     for r in engine.run(reqs):

@@ -62,14 +62,6 @@ class ModelConfig:
     # ``residual_in_fp32``; the ssm family only)
     residual_in_fp32: bool = False
     attn_q_block: int = 512              # q-block size for scanned attention
-    # windowed ring-buffer KV cache for sliding-window local layers —
-    # full-context cache only on global layers (gemma3: 52 of 62 layers
-    # keep a 1024-slot ring instead of 32k+); the paper's tight-partition
-    # idea applied to the KV cache itself
-    windowed_cache: bool = False
-    # int8 KV cache with per-(token, head) scales — halves decode HBM
-    # (dense decoder path; see attention.mha_decode_quant)
-    kv_quant: bool = False
     # 'onehot' contracts a one-hot matrix with the (vocab-sharded) table —
     # scatter/gather-free, the TPU-native choice; 'gather' is the classic
     # lookup (cheaper FLOPs, but XLA all-gathers around the sharded table)

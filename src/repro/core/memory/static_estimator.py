@@ -117,14 +117,6 @@ def kv_cache_bytes(cfg: ModelConfig, batch: int, context: int,
             nheads * (d_inner // max(nheads, 1)) * cfg.ssm_state
             + cfg.conv_width * (d_inner + 2 * cfg.ssm_state)) * dtype_bytes
         return ssm_bytes + n_attn_layers * batch * context * per_tok_layer
-    # windowed ring caches for local layers — only when the model actually
-    # allocates them (cfg.windowed_cache); the estimator must match the
-    # implementation, not the ideal (EXPERIMENTS §Perf hillclimb 3)
-    if cfg.windowed_cache and cfg.sliding_window and cfg.global_every:
-        n_global = cfg.n_layers // cfg.global_every
-        n_local = cfg.n_layers - n_global
-        local_ctx = min(context, cfg.sliding_window)
-        return batch * per_tok_layer * (n_global * context + n_local * local_ctx)
     return n_attn_layers * batch * context * per_tok_layer
 
 

@@ -237,8 +237,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool,
             cfg, preset.batch // (preset.microbatches
                                   if preset.kind == "train" else 1),
             preset.seq)
-        cache_b = kv_cache_bytes(cfg, preset.batch, preset.seq,
-                                 dtype_bytes=1 if cfg.kv_quant else 2)
+        cache_b = kv_cache_bytes(cfg, preset.batch, preset.seq)
         res.hbm_bytes = analytic_hbm_bytes(
             cfg, preset, n_dev, params_bytes=n_total * 2,
             opt_bytes=opt_b, cache_bytes=cache_b, act_bytes=act_b)

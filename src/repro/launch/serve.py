@@ -65,8 +65,7 @@ def serve_with_early_restart(cfg: ModelConfig, params: dict,
                     cfg, params,
                     EngineConfig(max_batch=len(requests),
                                  max_context=max_context,
-                                 partition_gb=partition_gb,
-                                 predict=partition_gb is not None),
+                                 partition_gb=partition_gb),
                     backend=backend)
                 out = engine.run(requests)
         except NeedsLargerPartition as e:

@@ -183,9 +183,9 @@ def mha_decode_append(params: dict, x: jax.Array, cfg: ModelConfig,
     attended from the cache and the token at ``index`` from its own k/v,
     so nothing cache-sized is written here.  Returns (y, k_new, v_new),
     k_new/v_new [B,1,KH,hd] in the cache's dtype, for the caller to write
-    at ``index`` (:func:`mha_decode` does that write itself).  Scores and
-    softmax are f32; the new token is rounded through the cache's dtype,
-    as it would be had it been read back from the cache.
+    at ``index`` (:func:`write_positions`).  Scores and softmax are f32;
+    the new token is rounded through the cache's dtype, as it would be had
+    it been read back from the cache.
     """
     positions = jnp.full((x.shape[0], 1), index, jnp.int32)
     q, k_new, v_new = _project_qkv(params, x, cfg, positions,
@@ -217,17 +217,18 @@ def mha_decode_append(params: dict, x: jax.Array, cfg: ModelConfig,
     return constrain(y, ("batch", "seq", None)), k_new, v_new
 
 
-def mha_decode(params: dict, x: jax.Array, cfg: ModelConfig,
-               cache_k: jax.Array, cache_v: jax.Array, index: jax.Array,
-               window=None, chunk=None):
-    """One-token decode that writes its own cache: :func:`mha_decode_append`
-    with k/v then written at ``index``.  x:[B,1,d]; cache_k/v:[B,C,KH,hd];
-    index: scalar current position.  Returns (y, cache_k, cache_v)."""
-    y, k_new, v_new = mha_decode_append(params, x, cfg, cache_k, cache_v,
-                                        index, window=window, chunk=chunk)
+def write_positions(caches: dict, new: dict, index) -> dict:
+    """``caches`` with each stack of ``new``, [L, B, S, KH, hd], written
+    at positions ``index..index+S-1`` of the stacked [L, B, C, KH, hd]
+    cache of that name; the other caches as they are.
+
+    The layer scans read the caches and return only the positions they
+    add (one, in a decode step); the donated stacks then take them in
+    place.  A scan that returned whole layer caches would restack (copy)
+    all of them on every step."""
     upd = jax.lax.dynamic_update_slice_in_dim
-    return (y, upd(cache_k, k_new, index, axis=1),
-            upd(cache_v, v_new, index, axis=1))
+    return {**caches, **{name: upd(caches[name], pos, index, axis=2)
+                         for name, pos in new.items()}}
 
 
 def _attend(q, k, v, q_pos, k_pos, window, chunk, causal, cfg,
@@ -285,86 +286,3 @@ def init_kv_cache(cfg: ModelConfig, n_layers: int, batch: int, context: int,
     kh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     shape = (n_layers, batch, context, kh, hd)
     return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-
-
-def mha_decode_windowed(params: dict, x: jax.Array, cfg: ModelConfig,
-                        cache_k: jax.Array, cache_v: jax.Array,
-                        index: jax.Array):
-    """One-token decode against a ring-buffer cache of ``window`` slots.
-
-    cache_k/v: [B, W, KH, hd].  Slot ``index % W`` is overwritten; slot j
-    holds absolute position p_j = index - ((index - j) mod W), i.e. exactly
-    the last W positions — the sliding window needs no extra mask beyond
-    p_j >= 0 (warmup).
-    """
-    w = cache_k.shape[1]
-    positions = jnp.full((x.shape[0], 1), index, jnp.int32)
-    q, k_new, v_new = _project_qkv(params, x, cfg, positions,
-                                   rope=not _no_rope(cfg))
-    slot = jnp.mod(index, w)
-    cache_k = jax.lax.dynamic_update_slice_in_dim(
-        cache_k, k_new.astype(cache_k.dtype), slot, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(
-        cache_v, v_new.astype(cache_v.dtype), slot, axis=1)
-    j = jnp.arange(w)
-    k_pos = index - jnp.mod(index - j, w)
-    bias = jnp.where(k_pos >= 0, 0.0, NEG_INF).astype(jnp.float32)[None, :]
-    out = _sdpa(q, cache_k.astype(q.dtype), cache_v.astype(q.dtype), bias,
-                cfg)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return constrain(y, ("batch", "seq", None)), cache_k, cache_v
-
-
-# -- int8-quantized KV cache (decode) -----------------------------------------
-
-def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Per-(token, head) symmetric int8: x [B,S,KH,hd] ->
-    (q int8 [B,S,KH,hd], scale f32 [B,S,KH,1])."""
-    scale = (jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-             / 127.0 + 1e-8)
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale
-
-
-def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
-    return (q.astype(jnp.float32) * scale).astype(dtype)
-
-
-def init_kv_cache_quant(cfg: ModelConfig, n_layers: int, batch: int,
-                        context: int):
-    """int8 caches + f32 scales, stacked for scan-over-layers decode."""
-    kh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    shape = (n_layers, batch, context, kh, hd)
-    sshape = (n_layers, batch, context, kh, 1)
-    z = jnp.zeros
-    return {"k_q": z(shape, jnp.int8), "k_s": z(sshape, jnp.float32),
-            "v_q": z(shape, jnp.int8), "v_s": z(sshape, jnp.float32)}
-
-
-def mha_decode_quant(params: dict, x: jax.Array, cfg: ModelConfig,
-                     k_q, k_s, v_q, v_s, index: jax.Array,
-                     window=None, chunk=None):
-    """One-token decode against an int8 KV cache.
-
-    Halves the decode HBM footprint AND the memory-roofline term (the cache
-    read dominates decode); per-(token, head) scales keep the logit error
-    within bf16 noise (validated in tests to ~2% relative).
-    """
-    positions = jnp.full((x.shape[0], 1), index, jnp.int32)
-    q, k_new, v_new = _project_qkv(params, x, cfg, positions,
-                                   rope=not _no_rope(cfg))
-    knq, kns = quantize_kv(k_new)
-    vnq, vns = quantize_kv(v_new)
-    upd = jax.lax.dynamic_update_slice_in_dim
-    k_q = upd(k_q, knq, index, axis=1)
-    k_s = upd(k_s, kns, index, axis=1)
-    v_q = upd(v_q, vnq, index, axis=1)
-    v_s = upd(v_s, vns, index, axis=1)
-    k_pos = jnp.arange(k_q.shape[1])
-    valid = (k_pos <= index) & _window_valid(k_pos, index, window, chunk)
-    bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)[None, :]
-    k = dequantize_kv(k_q, k_s, q.dtype)
-    v = dequantize_kv(v_q, v_s, q.dtype)
-    out = _sdpa(q, k, v, bias, cfg)
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
-    return constrain(y, ("batch", "seq", None)), (k_q, k_s, v_q, v_s)

@@ -125,18 +125,20 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
 
     def body(h, xs):
         lp, xlp, ck, cv, xk, xv = xs
-        out, ck, cv = attn.mha_decode(
+        out, k_new, v_new = attn.mha_decode_append(
             lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg, ck, cv, index)
         h = h + out
         h = h + attn.mha_cross(
             xlp, rmsnorm(h, lp["norm_cross"], cfg.norm_eps),
             xk.astype(h.dtype), xv.astype(h.dtype), cfg)
         h = h + mlp(lp, rmsnorm(h, lp["norm2"], cfg.norm_eps), cfg)
-        return h, (ck, cv)
+        return h, (k_new, v_new)
 
-    x, (ks, vs) = jax.lax.scan(
+    # the cross caches are read only; the self-attention stacks take the
+    # new position
+    x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["decoder"], params["cross"], caches["k"],
                   caches["v"], caches["cross_k"], caches["cross_v"]))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    new = {**caches, "k": ks, "v": vs}
-    return unembed(params, x, cfg), new
+    return unembed(params, x, cfg), attn.write_positions(
+        caches, {"k": k_new, "v": v_new}, index)

@@ -127,22 +127,21 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
         lp_group, conv_cg, state_cg, ck, cv = xs
         h, (conv_cg, state_cg) = jax.lax.scan(
             mamba_step, h, (lp_group, conv_cg, state_cg))
-        out, ck, cv = attn.mha_decode(
+        out, k_new, v_new = attn.mha_decode_append(
             shared, rmsnorm(h, shared["norm1"], cfg.norm_eps), cfg, ck, cv,
             index)
         h = h + out
         h = h + mlp(shared, rmsnorm(h, shared["norm2"], cfg.norm_eps), cfg)
-        return h, (conv_cg, state_cg, ck, cv)
+        return h, (conv_cg, state_cg, k_new, v_new)
 
-    x, (conv_g, state_g, ks, vs) = jax.lax.scan(
+    x, (conv_g, state_g, k_new, v_new) = jax.lax.scan(
         group_body, x,
         (grouped, conv_g, state_g, caches["attn_k"], caches["attn_v"]))
-    new = {
-        "ssm": {
-            "conv": conv_g.reshape((n_groups * m,) + conv_g.shape[2:]),
-            "state": state_g.reshape((n_groups * m,) + state_g.shape[2:]),
-        },
-        "attn_k": ks, "attn_v": vs,
+    new = attn.write_positions(caches, {"attn_k": k_new, "attn_v": v_new},
+                               index)
+    new["ssm"] = {
+        "conv": conv_g.reshape((n_groups * m,) + conv_g.shape[2:]),
+        "state": state_g.reshape((n_groups * m,) + state_g.shape[2:]),
     }
     if remainder:
         x, (conv_t, state_t) = jax.lax.scan(

@@ -102,12 +102,12 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     return transformer.decode_step(params, cfg, token, index, caches)
 
 
-def prefill_length(cfg: ModelConfig, caches: dict, s: int) -> int | None:
+def prefill_length(cfg: ModelConfig, s: int) -> int | None:
     """The positions one :func:`prefill_caches` call takes for an
-    ``s``-position prompt into ``caches``, the layout :func:`init_caches`
-    made, or None where the prompt is replayed one :func:`decode_step` per
-    position (:func:`repro.models.transformer.prefill_length`)."""
-    return transformer.prefill_length(cfg, caches, s)
+    ``s``-position prompt, or None where the prompt is replayed one
+    :func:`decode_step` per position
+    (:func:`repro.models.transformer.prefill_length`)."""
+    return transformer.prefill_length(cfg, s)
 
 
 def prefill_caches(params: dict, cfg: ModelConfig, tokens: jax.Array,
@@ -150,40 +150,17 @@ KV_SPEC = ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
 SSM_CONV_SPEC = ("layers", "batch", None, "ssm_inner")
 SSM_STATE_SPEC = ("layers", "batch", "ssm_inner", None, None)
 
-
-WKV_LOCAL_SPEC = ("layers", "layers2", "batch", "cache_seq", "kv_heads",
-                  "head_dim")
-WKV_TAIL_SPEC = ("layers", "batch", "cache_seq", "kv_heads", "head_dim")
+SSM_SPECS = {"conv": SSM_CONV_SPEC, "state": SSM_STATE_SPEC}
 
 
 def cache_specs(cfg: ModelConfig) -> dict:
     """Logical axes mirroring :func:`init_caches`' structure."""
     if cfg.family == "ssm":
-        return {"ssm": {"conv": SSM_CONV_SPEC, "state": SSM_STATE_SPEC}}
-    if cfg.kv_quant and cfg.family in ("dense", "vlm") \
-            and not cfg.n_experts:
-        return {"k_q": KV_SPEC, "k_s": KV_SPEC,
-                "v_q": KV_SPEC, "v_s": KV_SPEC}
-    if (cfg.windowed_cache and cfg.sliding_window and cfg.global_every
-            and not cfg.n_experts and cfg.family not in ("audio", "hybrid")):
-        from repro.models.transformer import windowed_layout
-        _, _, tail = windowed_layout(cfg)
-        out = {"local_k": WKV_LOCAL_SPEC, "local_v": WKV_LOCAL_SPEC,
-               "global_k": KV_SPEC, "global_v": KV_SPEC}
-        if tail:
-            out["tail_k"] = WKV_TAIL_SPEC
-            out["tail_v"] = WKV_TAIL_SPEC
-        return out
+        return {"ssm": SSM_SPECS}
     if cfg.family == "hybrid":
-        from repro.models.hybrid import _group_shape
-        _, remainder = _group_shape(cfg)
-        out = {
-            "ssm": {"conv": SSM_CONV_SPEC, "state": SSM_STATE_SPEC},
-            "attn_k": KV_SPEC, "attn_v": KV_SPEC,
-        }
-        if remainder:
-            out["ssm_tail"] = {"conv": SSM_CONV_SPEC,
-                               "state": SSM_STATE_SPEC}
+        out = {"ssm": SSM_SPECS, "attn_k": KV_SPEC, "attn_v": KV_SPEC}
+        if hybrid._group_shape(cfg)[1]:
+            out["ssm_tail"] = SSM_SPECS
         return out
     if cfg.family == "audio":
         return {"k": KV_SPEC, "v": KV_SPEC,

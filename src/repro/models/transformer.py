@@ -9,7 +9,6 @@ window/chunk vectors.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -50,15 +49,6 @@ def all_layers_global(cfg: ModelConfig) -> bool:
     full-sequence attention may take the flash kernel, whose window must be
     static (a traced per-layer window keeps it on the XLA path)."""
     return cfg.sliding_window is None and cfg.attention_chunk is None
-
-
-def windowed_layout(cfg: ModelConfig) -> tuple[int, int, int]:
-    """(n_groups, group_size, n_tail) for the windowed-cache decode layout:
-    groups of (global_every) layers = (ge-1) local + 1 global; trailing
-    local layers form the tail (gemma3: 62 = 10x6 + 2)."""
-    ge = cfg.global_every
-    n_groups = cfg.n_layers // ge
-    return n_groups, ge, cfg.n_layers - n_groups * ge
 
 
 def remat_layer(fn):
@@ -194,9 +184,9 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
             # [L, B, S, KH, hd] each, into the donated stacks at position 0;
             # positions past the cache are pads
             c = caches["k"].shape[2]
-            upd = jax.lax.dynamic_update_slice_in_dim
-            caches = {name: upd(caches[name], new[:, :, :c], 0, axis=2)
-                      for name, new in zip(("k", "v"), kv)}
+            caches = attn.write_positions(
+                caches, {name: new[:, :, :c]
+                         for name, new in zip(("k", "v"), kv)}, 0)
 
     if last_only:
         x = x[:, -1:] if length is None else \
@@ -281,33 +271,10 @@ def _forward_interleaved_moe(params, cfg, x, positions, windows):
 # -- decode ---------------------------------------------------------------------------
 
 def init_caches(cfg: ModelConfig, batch: int, context: int) -> dict:
-    caches: dict[str, Any] = {}
     if cfg.family == "ssm":
-        caches["ssm"] = ssm_lib.init_ssm_cache(cfg, cfg.n_layers, batch)
-    elif cfg.kv_quant and not cfg.n_experts:
-        # int8 KV: dense/VLM only — MoE top-k routing is discontinuous and
-        # amplifies quantization perturbations into expert flips
-        caches.update(attn.init_kv_cache_quant(cfg, cfg.n_layers, batch,
-                                               context))
-    elif (cfg.windowed_cache and cfg.sliding_window and cfg.global_every
-          and not cfg.n_experts):
-        ng, ge, tail = windowed_layout(cfg)
-        w = min(cfg.sliding_window, context)
-        kh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-        import jax.numpy as _jnp
-        caches["local_k"] = _jnp.zeros((ng, ge - 1, batch, w, kh, hd),
-                                       _jnp.bfloat16)
-        caches["local_v"] = _jnp.zeros_like(caches["local_k"])
-        gk, gv = attn.init_kv_cache(cfg, ng, batch, context)
-        caches["global_k"], caches["global_v"] = gk, gv
-        if tail:
-            caches["tail_k"] = _jnp.zeros((tail, batch, w, kh, hd),
-                                          _jnp.bfloat16)
-            caches["tail_v"] = _jnp.zeros_like(caches["tail_k"])
-    else:
-        k, v = attn.init_kv_cache(cfg, cfg.n_layers, batch, context)
-        caches["k"], caches["v"] = k, v
-    return caches
+        return {"ssm": ssm_lib.init_ssm_cache(cfg, cfg.n_layers, batch)}
+    k, v = attn.init_kv_cache(cfg, cfg.n_layers, batch, context)
+    return {"k": k, "v": v}
 
 
 def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
@@ -331,30 +298,6 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
             body, _residual(x, cfg), (params["layers"], caches["ssm"]["conv"],
                                       caches["ssm"]["state"]))
         caches = {"ssm": {"conv": conv_cs, "state": state_cs}}
-    elif "k_q" in caches:
-        def body_q(carry, xs):
-            h = carry
-            lp, kq, ks, vq, vs, win = xs
-            window = jnp.where(win >= GLOBAL, jnp.int32(2 ** 30), win)
-            chunk = window if is_chunked else None
-            w_arg = None if is_chunked else window
-            out, new_c = attn.mha_decode_quant(
-                lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg, kq, ks, vq,
-                vs, index, window=w_arg, chunk=chunk)
-            h = h + out
-            hn = rmsnorm(h, lp["norm2"], cfg.norm_eps)
-            if cfg.n_experts:
-                out2, _ = moe_lib.moe_layer(lp, hn, cfg)
-            else:
-                out2 = mlp(lp, hn, cfg)
-            return h + out2, new_c
-
-        x, (kq, ks, vq, vs) = jax.lax.scan(
-            body_q, x, (params["layers"], caches["k_q"], caches["k_s"],
-                        caches["v_q"], caches["v_s"], windows))
-        caches = {"k_q": kq, "k_s": ks, "v_q": vq, "v_s": vs}
-    elif "local_k" in caches:
-        x, caches = _decode_windowed(params, cfg, x, index, caches)
     elif cfg.n_experts and cfg.moe_every > 1:
         x, caches = _decode_interleaved_moe(params, cfg, x, index, caches,
                                             windows)
@@ -376,44 +319,41 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
                 out2 = mlp(lp, hn, cfg)
             return h + out2, (k_new, v_new)
 
-        # the layers read the cache and return only the new position; the
-        # donated stacks then take it in place, [L, B, 1, KH, hd] each
         x, (k_new, v_new) = jax.lax.scan(
             body, x, (params["layers"], caches["k"], caches["v"], windows))
-        upd = jax.lax.dynamic_update_slice_in_dim
-        caches = {"k": upd(caches["k"], k_new, index, axis=2),
-                  "v": upd(caches["v"], v_new, index, axis=2)}
+        caches = attn.write_positions(caches, {"k": k_new, "v": v_new}, index)
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
     logits = unembed(params, x, cfg)
     return logits, caches
 
 
-def prefill_length(cfg: ModelConfig, caches: dict, s: int) -> int | None:
+def prefill_length(cfg: ModelConfig, s: int) -> int | None:
     """The positions one prefill call (:func:`forward` with ``caches``)
     takes for an ``s``-position prompt, or None where the prompt is
     replayed through :func:`decode_step` one position at a time.
 
-    The prefill writes two layouts, as :func:`init_caches` made them: an
-    SSM's ``{"ssm": {"conv", "state"}}`` off the Pallas path, which returns
-    no state, taken at ``s`` positions since a pad would enter the state;
-    and ``{"k", "v"}`` behind the plain layer scan of a dense decoder
-    whose every layer attends globally, padded up to a multiple of 128
-    positions, or of ``attn_q_block`` past it, where attention scans query
-    blocks instead of holding the whole [S, S] scores: a few programs
-    serve every prompt length.  Expert routing, windows, chunks and patch
-    embeddings keep the replay."""
-    if set(caches) == {"ssm"}:
-        ok = cfg.family == "ssm" and cfg.ssm_impl != "pallas"
-        return s if ok else None
-    if not (set(caches) == {"k", "v"} and cfg.family == "dense"
-            and not cfg.n_experts and all_layers_global(cfg)):
+    The prefill writes two layouts: an SSM's off the Pallas path, which
+    returns no state, taken at ``s`` positions since a pad would enter the
+    state; and the KV caches behind the plain layer scan of a dense decoder
+    with no experts whose every layer attends globally, padded up to a
+    multiple of 128 positions, or of ``attn_q_block`` past it, where
+    attention scans query blocks instead of holding the whole [S, S]
+    scores: a few programs serve every prompt length.  Expert routing,
+    windows, chunks, patch embeddings, the hybrid and the encoder-decoder
+    keep the replay."""
+    if cfg.family == "ssm":
+        return s if cfg.ssm_impl != "pallas" else None
+    if not (cfg.family == "dense" and not cfg.n_experts
+            and all_layers_global(cfg)):
         return None
     step = cfg.attn_q_block if s > cfg.attn_q_block else 128
     return -(-s // step) * step
 
 
 def _decode_interleaved_moe(params, cfg, x, index, caches, windows):
+    """llama4's decode layers, in :func:`_forward_interleaved_moe`'s
+    order.  Returns (x, caches with every layer's new position written)."""
     L, m = cfg.n_layers, cfg.moe_every
     n_pairs = L // m
     is_chunked = chunked_flags(cfg)
@@ -431,10 +371,10 @@ def _decode_interleaved_moe(params, cfg, x, index, caches, windows):
         window = jnp.where(win >= GLOBAL, jnp.int32(2 ** 30), win)
         chunk = window if is_chunked else None
         w_arg = None if is_chunked else window
-        out, ck, cv = attn.mha_decode(
+        out, k_new, v_new = attn.mha_decode_append(
             lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg, ck, cv, index,
             window=w_arg, chunk=chunk)
-        return h + out, ck, cv
+        return h + out, k_new, v_new
 
     def pair_body(carry, xs):
         h = carry
@@ -442,77 +382,31 @@ def _decode_interleaved_moe(params, cfg, x, index, caches, windows):
 
         def inner(h2, xs2):
             lp, dlp, win, ck, cv = xs2
-            h2, ck, cv = one_attn(h2, lp, ck, cv, win)
+            h2, k_new, v_new = one_attn(h2, lp, ck, cv, win)
             h2 = h2 + mlp(dlp, rmsnorm(h2, lp["norm2"], cfg.norm_eps), cfg)
-            return h2, (ck, cv)
+            return h2, (k_new, v_new)
 
+        ks, vs = [], []
         if m > 1:
-            h, (cks, cvs) = jax.lax.scan(
+            h, (k_new, v_new) = jax.lax.scan(
                 inner, h,
                 (jax.tree_util.tree_map(lambda a: a[:m - 1], lp_group),
                  dense_group, win_group[:m - 1], ckg[:m - 1], cvg[:m - 1]))
+            ks, vs = [k_new], [v_new]
         lp_last = jax.tree_util.tree_map(lambda a: a[m - 1], lp_group)
-        h, ck_l, cv_l = one_attn(h, lp_last, ckg[m - 1], cvg[m - 1],
-                                 win_group[m - 1])
+        h, k_new, v_new = one_attn(h, lp_last, ckg[m - 1], cvg[m - 1],
+                                   win_group[m - 1])
         out, _ = moe_lib.moe_layer(
             moe_lp, rmsnorm(h, lp_last["norm2"], cfg.norm_eps), cfg)
-        h = h + out
-        if m > 1:
-            ck_all = jnp.concatenate([cks, ck_l[None]], axis=0)
-            cv_all = jnp.concatenate([cvs, cv_l[None]], axis=0)
-        else:
-            ck_all, cv_all = ck_l[None], cv_l[None]
-        return h, (ck_all, cv_all)
+        # [m, B, 1, KH, hd]: one position per layer of the pair
+        return h + out, (jnp.concatenate(ks + [k_new[None]], axis=0),
+                         jnp.concatenate(vs + [v_new[None]], axis=0))
 
-    x, (ks, vs) = jax.lax.scan(
+    x, (k_new, v_new) = jax.lax.scan(
         pair_body, x,
         (grouped, dense_grouped, params["moe_layers"], win_grouped,
          k_grouped, v_grouped))
-    caches = {"k": ks.reshape((L,) + ks.shape[2:]),
-              "v": vs.reshape((L,) + vs.shape[2:])}
-    return x, caches
-
-
-def _decode_windowed(params, cfg, x, index, caches):
-    """Decode with ring-buffer caches on local layers (windowed_cache=True).
-
-    Layers are processed in groups of ``global_every``: (ge-1) local layers
-    use [B, W, KH, hd] ring caches, the group's final layer is global with a
-    full-context cache; trailing local layers form the tail.
-    """
-    ng, ge, tail = windowed_layout(cfg)
-    lyr = params["layers"]
-    body_p = jax.tree_util.tree_map(
-        lambda a: a[:ng * ge].reshape((ng, ge) + a.shape[1:]), lyr)
-
-    def mlp_block(h, lp):
-        return h + mlp(lp, rmsnorm(h, lp["norm2"], cfg.norm_eps), cfg)
-
-    def local_step(h, xs):
-        lp, ck, cv = xs
-        out, ck, cv = attn.mha_decode_windowed(
-            lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), cfg, ck, cv, index)
-        h = mlp_block(h + out, lp)
-        return h, (ck, cv)
-
-    def group_body(h, xs):
-        lp_group, lck, lcv, gck, gcv = xs
-        local_p = jax.tree_util.tree_map(lambda a: a[:ge - 1], lp_group)
-        h, (lck, lcv) = jax.lax.scan(local_step, h, (local_p, lck, lcv))
-        lp_g = jax.tree_util.tree_map(lambda a: a[ge - 1], lp_group)
-        out, gck, gcv = attn.mha_decode(
-            lp_g, rmsnorm(h, lp_g["norm1"], cfg.norm_eps), cfg, gck, gcv,
-            index)
-        h = mlp_block(h + out, lp_g)
-        return h, (lck, lcv, gck, gcv)
-
-    x, (lk, lv, gk, gv) = jax.lax.scan(
-        group_body, x, (body_p, caches["local_k"], caches["local_v"],
-                        caches["global_k"], caches["global_v"]))
-    new = {"local_k": lk, "local_v": lv, "global_k": gk, "global_v": gv}
-    if tail:
-        tail_p = jax.tree_util.tree_map(lambda a: a[ng * ge:], lyr)
-        x, (tk, tv) = jax.lax.scan(
-            local_step, x, (tail_p, caches["tail_k"], caches["tail_v"]))
-        new["tail_k"], new["tail_v"] = tk, tv
-    return x, new
+    # [n_pairs, m, B, 1, KH, hd] -> [L, B, 1, KH, hd], layers in order
+    return x, attn.write_positions(
+        caches, {"k": k_new.reshape((L,) + k_new.shape[2:]),
+                 "v": v_new.reshape((L,) + v_new.shape[2:])}, index)

@@ -50,8 +50,9 @@ class Request:
 class EngineConfig:
     max_batch: int = 8
     max_context: int = 512
-    partition_gb: float | None = None      # slice the engine believes it has
-    predict: bool = True                   # paper: time-series early restart
+    #: slice the engine believes it has; set, the time-series predictor
+    #: raises the early restart when the batch will not fit it (paper)
+    partition_gb: float | None = None
     #: SLO-aware restart trade (mirrors the simulator's grow trade,
     #: cost.serving_grow_cost): when both are set, the engine restarts as
     #: soon as the predictor's graded OOM risk prices the expected crash
@@ -116,7 +117,7 @@ class ServeEngine:
                 cache_bytes=pytree_nbytes(caches))
             # the padded prompt batch, and the pads after it that the
             # prefill program for this layout takes
-            length = registry.prefill_length(cfg, caches, prompt_len)
+            length = registry.prefill_length(cfg, prompt_len)
             toks = np.zeros((b, length or prompt_len), np.int32)
             for i, r in enumerate(requests):
                 toks[i, :len(r.prompt)] = r.prompt
@@ -228,7 +229,7 @@ class ServeEngine:
     def _check_memory(self, caches, upto: int) -> None:
         with span("engine.predictor"):
             self._note_iteration(caches, upto)
-            if not (self.ecfg.predict and self.ecfg.partition_gb):
+            if not self.ecfg.partition_gb:
                 return
             stats = self.accountant.history[-1]
             pred = self.predictor.observe(stats.requested_bytes,

@@ -109,11 +109,13 @@ def test_decode_step_cache_invariants(arch, smoke_state):
      ("zamba2-7b", 16), ("whisper-medium", 16),
      # MoE MLP and the VLM decoder in the plain attention body
      ("grok-1-314b", 16), ("pixtral-12b", 16),
+     # interleaved dense / MoE pairs with chunked attention
+     ("llama4-maverick-400b-a17b", 16),
      # the last step writes the cache's last slot (index == C - 1)
      ("qwen3-0.6b", 8)],
     ids=["qwen3-0.6b", "gemma3-27b", "mamba2-2.7b", "zamba2-7b",
          "whisper-medium", "grok-1-314b", "pixtral-12b",
-         "qwen3-0.6b-last-slot"])
+         "llama4-maverick-400b-a17b", "qwen3-0.6b-last-slot"])
 def test_prefill_decode_consistency(arch, context, smoke_state):
     """Teacher-forced logits == step-by-step decode logits (f32)."""
     import dataclasses

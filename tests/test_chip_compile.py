@@ -149,7 +149,7 @@ def test_prefill_fits_one_chip_and_fills_the_donated_caches(
         lambda: registry.init_caches(cfg, rows, context)))
     engine = ServeEngine(cfg, params, EngineConfig(max_batch=rows,
                                                    max_context=context))
-    length = registry.prefill_length(cfg, caches, prompt)
+    length = registry.prefill_length(cfg, prompt)
     mem = engine._prefill.lower(
         params, _sds((rows, length), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip), caches).compile().memory_analysis()

@@ -133,8 +133,7 @@ def test_prefill_serves_what_the_replay_serves(bench, seed):
         0, hp["vocab_size"], (B, T)).astype(np.int32)
     seq, replayed = _decode(cfg, w, prompts)
     engine = ServeEngine(cfg, w, EngineConfig(max_batch=B,
-                                              max_context=T + K,
-                                              predict=False))
+                                              max_context=T + K))
     reqs = [Request(uid=i, prompt=prompts[i], max_new_tokens=K - 2)
             for i in range(B)]
     engine.run(reqs)
@@ -270,8 +269,7 @@ def test_setup_span_names_the_cache(arch, kind, tmp_path):
     cfg = get_smoke_config(arch)
     params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
     engine = ServeEngine(cfg, params, EngineConfig(max_batch=2,
-                                                   max_context=16,
-                                                   predict=False))
+                                                   max_context=16))
     reqs = [Request(uid=i, prompt=np.arange(1, 5, dtype=np.int32),
                     max_new_tokens=2) for i in range(2)]
     jax.profiler.start_trace(str(tmp_path))

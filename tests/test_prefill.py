@@ -9,7 +9,6 @@ itself through ``registry.decode_step``: the same served tokens, and the
 last prompt position's logits and the caches within bfloat16 rounding.
 """
 
-import dataclasses
 import hashlib
 
 import jax
@@ -74,7 +73,7 @@ def _prefill(params, cfg, prompts, context):
     """``registry.prefill_caches`` over ``prompts`` padded as the engine
     pads them: (logits, caches)."""
     caches = registry.init_caches(cfg, B, context)
-    n = registry.prefill_length(cfg, caches, S)
+    n = registry.prefill_length(cfg, S)
     tokens = np.zeros((B, n), np.int32)
     tokens[:, :S] = prompts
     return jax.jit(lambda p, t, i, c: registry.prefill_caches(
@@ -122,14 +121,12 @@ def test_engine_serves_the_replays_tokens(arch, monkeypatch):
         return span(name, **args)
     monkeypatch.setattr(engine_lib, "span", recorded)
     engine = ServeEngine(cfg, params, EngineConfig(max_batch=B,
-                                                   max_context=C,
-                                                   predict=False))
+                                                   max_context=C))
     reqs = [Request(uid=i, prompt=prompts[i], max_new_tokens=K)
             for i in range(B)]
     engine.run(reqs)
     prefilled = arch in PREFILLED
-    assert (registry.prefill_length(
-        cfg, registry.init_caches(cfg, B, C), S) is not None) == prefilled
+    assert (registry.prefill_length(cfg, S) is not None) == prefilled
     assert replays == [{"positions": S, "calls": 1 if prefilled else S}]
     want_logits, _, want = _replay(params, cfg, prompts, K)
     assert [r.generated for r in reqs] == want.tolist()
@@ -158,7 +155,7 @@ def test_restart_comes_at_the_same_decode_step(monkeypatch):
             log=lambda m: at.append(len(reqs[0].generated)))
         return at, [r.generated for r in res.requests]
     at, served = serve()
-    monkeypatch.setattr(registry, "prefill_length", lambda cfg, c, s: None)
+    monkeypatch.setattr(registry, "prefill_length", lambda cfg, s: None)
     assert serve() == (at, served)
     assert len(at) == 1 and 0 < at[0] < 12
 
@@ -174,9 +171,7 @@ def test_prefill_length(arch, prompt, length):
     ``attn_q_block`` (512) past it, so that a few programs serve every
     prompt length and a long prompt's attention scans query blocks; an
     SSM's prompt is taken as it is, since a pad would enter its state."""
-    cfg = get_config(arch)
-    caches = jax.eval_shape(lambda: registry.init_caches(cfg, 8, 4096))
-    assert registry.prefill_length(cfg, caches, prompt) == length
+    assert registry.prefill_length(get_config(arch), prompt) == length
 
 
 def test_prompts_of_one_bucket_share_a_prefill_program():
@@ -185,8 +180,7 @@ def test_prompts_of_one_bucket_share_a_prefill_program():
     cfg = get_smoke_config("qwen3-1.7b")
     params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
     engine = ServeEngine(cfg, params, EngineConfig(max_batch=B,
-                                                   max_context=C,
-                                                   predict=False))
+                                                   max_context=C))
     programs = []
     for n in (S, S - 2):
         engine.run([Request(uid=i, prompt=p[:n], max_new_tokens=2)
@@ -223,6 +217,37 @@ def test_decode_step_lowers_as_before(arch, rows, context):
     assert digest == DECODE_STEP_SHA256[arch, rows, context]
 
 
+#: sha256 of the engine's prefill for the cells' rows, padded prompt
+#: lengths and contexts, lowered (``Lowered.as_text()``, JAX 0.9.0) before
+#: the int8 and ring-buffer cache layouts were deleted: the prefill
+#: programs the cells run do not change with it
+PREFILL_SHA256 = {
+    ("qwen3-1.7b", 32, 256, 512):
+        "4a961809484c7ad2262c731e1da7a696d74b39d4f24c7f8fcbf40c0458be1d77",
+    ("qwen3-1.7b", 64, 128, 512):
+        "3069f031dcb0c12fe37569657b2b9ceff37693fa1cedd2dec0f57e5174ac0eb1",
+    ("mamba2-2.7b", 16, 80, 256):
+        "f5181c9f166f6a1711fbf2e5828f199574abaae51388110231618d9072a0b089",
+    ("mamba2-2.7b", 16, 112, 256):
+        "602e3ce51dbd93a1a660601c2ffec82e03f44602c63cd1c386542aa77e75f366",
+}
+
+
+@pytest.mark.parametrize("arch, rows, length, context", list(PREFILL_SHA256))
+def test_prefill_lowers_as_before(arch, rows, length, context):
+    cfg = get_config(arch)
+
+    def prefill(p, t, n, c):            # named as the engine names it
+        return registry.prefill_caches(p, cfg, t, n, c)
+    caches = jax.eval_shape(lambda: registry.init_caches(cfg, rows, context))
+    text = jax.jit(prefill, donate_argnums=(3,), keep_unused=True).lower(
+        registry.abstract_params(cfg)[0],
+        jax.ShapeDtypeStruct((rows, length), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32), caches).as_text()
+    digest = hashlib.sha256(text.encode()).hexdigest()
+    assert digest == PREFILL_SHA256[arch, rows, length, context]
+
+
 def test_ragged_prompts_keep_their_trailing_pads():
     """A batch's shorter prompts are padded at their end with id 0 and the
     pads go into the caches, in the prefill as in the replay."""
@@ -233,7 +258,7 @@ def test_ragged_prompts_keep_their_trailing_pads():
     padded[0, 4:] = 0
     reqs = [Request(uid=i, prompt=prompts[i][:4] if i == 0 else prompts[i],
                     max_new_tokens=K) for i in range(B)]
-    ServeEngine(cfg, params, EngineConfig(max_batch=B, max_context=C,
-                                          predict=False)).run(reqs)
+    ServeEngine(cfg, params,
+                EngineConfig(max_batch=B, max_context=C)).run(reqs)
     assert [r.generated for r in reqs] == \
         _replay(params, cfg, padded, K)[2].tolist()

@@ -1,5 +1,5 @@
 """Unit tests: sharding rules/policies, partitionable loss & embedding,
-windowed KV cache, HLO parser."""
+cache specs, HLO parser."""
 
 import dataclasses
 
@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.configs import get_smoke_config
+from repro.configs import ALL_ARCHS, get_smoke_config
 from repro.models import registry
 from repro.models.layers import cross_entropy_loss, embed_tokens
 from repro.models.module import cast_tree
@@ -119,56 +119,19 @@ class TestPartitionableOps:
                                    atol=1e-2)  # bf16 matmul rounding
 
 
-class TestWindowedCache:
-    def _cfg(self, window=8):
-        cfg = get_smoke_config("gemma3-27b")
-        return dataclasses.replace(cfg, windowed_cache=True,
-                                   sliding_window=window)
-
-    def test_cache_structure(self):
-        cfg = self._cfg()
-        caches = registry.init_caches(cfg, 2, 64)
-        assert set(caches) >= {"local_k", "local_v", "global_k", "global_v"}
-        assert caches["local_k"].shape[3] == 8      # ring size == window
-        assert caches["global_k"].shape[2] == 64    # full context
-
-    def test_cache_specs_match_structure(self):
-        cfg = self._cfg()
-        caches = registry.init_caches(cfg, 2, 64)
-        specs = registry.cache_specs(cfg)
-        assert set(specs) == set(caches)
-        for k in caches:
-            assert len(specs[k]) == caches[k].ndim
-
-    @pytest.mark.parametrize("window", [4, 8])
-    def test_prefill_decode_consistency(self, window):
-        """Ring-buffer decode == teacher-forced prefill, past the point
-        where the ring wraps (the regression that matters)."""
-        cfg = self._cfg(window)
-        params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
-        params = cast_tree(params, jnp.float32)
-        S = 3 * window  # wraps the ring multiple times
-        batch = registry.make_dummy_batch(cfg, 2, S,
-                                          key=jax.random.PRNGKey(7))
-        full = registry.forward(params, cfg, batch).logits
-        caches = registry.init_caches(cfg, 2, S)
-        caches = jax.tree_util.tree_map(
-            lambda a: a.astype(jnp.float32), caches)
-        for i in range(S):
-            logits, caches = registry.decode_step(
-                params, cfg, batch["tokens"][:, i:i + 1], jnp.int32(i),
-                caches)
-            err = float(jnp.abs(logits[:, 0] - full[:, i]).max()
-                        / (jnp.abs(full[:, i]).max() + 1e-9))
-            assert err < 5e-4, f"step {i}: {err}"
-
-    def test_windowed_cache_is_smaller(self):
-        from repro.core.memory.accountant import pytree_nbytes
-        cfg_w = self._cfg()
-        cfg_f = dataclasses.replace(cfg_w, windowed_cache=False)
-        cw = pytree_nbytes(registry.init_caches(cfg_w, 2, 256))
-        cf = pytree_nbytes(registry.init_caches(cfg_f, 2, 256))
-        assert cw < cf * 0.6  # smoke cfg: only 1 of 2 layers is local
+@pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_cache_specs_match_structure(arch):
+    """The logical axes that place the caches (the pod's and the dry
+    run's) have :func:`registry.init_caches`' tree, one name per dim."""
+    cfg = get_smoke_config(arch)
+    caches = jax.eval_shape(lambda: registry.init_caches(cfg, 2, 64))
+    specs = registry.cache_specs(cfg)
+    is_spec = lambda x: isinstance(x, tuple)        # noqa: E731
+    assert (jax.tree.structure(specs, is_leaf=is_spec)
+            == jax.tree.structure(caches))
+    for spec, leaf in zip(jax.tree.leaves(specs, is_leaf=is_spec),
+                          jax.tree.leaves(caches)):
+        assert len(spec) == leaf.ndim, (spec, leaf.shape)
 
 
 class TestHloParser:
@@ -204,51 +167,6 @@ ENTRY %main.1 (x: f32[16]) -> f32[16] {
 """
         res = analyze(hlo)
         assert res["collectives"]["all-reduce"] == 64.0
-
-
-class TestQuantizedKV:
-    def test_quantize_roundtrip(self):
-        from repro.models.attention import dequantize_kv, quantize_kv
-        x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 4, 64),
-                              jnp.float32)
-        q, s = quantize_kv(x)
-        assert q.dtype == jnp.int8
-        back = dequantize_kv(q, s, jnp.float32)
-        np.testing.assert_allclose(back, x, atol=float(jnp.abs(x).max())
-                                   / 100)
-
-    def test_dense_decode_consistency_with_int8(self):
-        cfg = dataclasses.replace(get_smoke_config("qwen3-0.6b"),
-                                  kv_quant=True)
-        params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
-        params = cast_tree(params, jnp.float32)
-        S = 12
-        batch = registry.make_dummy_batch(cfg, 2, S,
-                                          key=jax.random.PRNGKey(7))
-        full = registry.forward(params, cfg, batch).logits
-        caches = registry.init_caches(cfg, 2, 16)
-        assert "k_q" in caches and caches["k_q"].dtype == jnp.int8
-        for i in range(S):
-            logits, caches = registry.decode_step(
-                params, cfg, batch["tokens"][:, i:i + 1], jnp.int32(i),
-                caches)
-            err = float(jnp.abs(logits[:, 0] - full[:, i]).max()
-                        / (jnp.abs(full[:, i]).max() + 1e-9))
-            assert err < 0.02, f"step {i}: {err}"
-
-    def test_int8_cache_is_half_size(self):
-        from repro.core.memory.accountant import pytree_nbytes
-        cfg = get_smoke_config("qwen3-0.6b")
-        cfg_q = dataclasses.replace(cfg, kv_quant=True)
-        full = pytree_nbytes(registry.init_caches(cfg, 2, 256))
-        quant = pytree_nbytes(registry.init_caches(cfg_q, 2, 256))
-        assert quant < full * 0.6  # int8 + f32 scales ~= 0.52x
-
-    def test_moe_not_quantized(self):
-        cfg = dataclasses.replace(get_smoke_config("grok-1-314b"),
-                                  kv_quant=True)
-        caches = registry.init_caches(cfg, 2, 16)
-        assert "k_q" not in caches  # MoE routing is perturbation-sensitive
 
 
 class TestKernelWiring:

@@ -121,8 +121,7 @@ class TestServeEngine:
         cfg = get_smoke_config("qwen3-0.6b")
         params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
         eng = ServeEngine(cfg, params, EngineConfig(max_batch=2,
-                                                    max_context=64,
-                                                    predict=False))
+                                                    max_context=64))
         reqs = [Request(uid=i, prompt=np.arange(4, dtype=np.int32),
                         max_new_tokens=8) for i in range(2)]
         out = eng.run(reqs)
@@ -138,8 +137,7 @@ class TestServeEngine:
         cfg = get_smoke_config("qwen3-0.6b")
         params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
         eng = ServeEngine(cfg, params, EngineConfig(max_batch=2,
-                                                    max_context=64,
-                                                    predict=False))
+                                                    max_context=64))
         def reqs():
             return [Request(uid=i, prompt=np.arange(4, dtype=np.int32),
                             max_new_tokens=8) for i in range(2)]
@@ -158,7 +156,7 @@ class TestServeEngine:
         # (a partition large enough that no early restart fires)
         pred_eng = ServeEngine(cfg, params,
                                EngineConfig(max_batch=2, max_context=64,
-                                            partition_gb=1e3, predict=True))
+                                            partition_gb=1e3))
         pred_eng.run(reqs())
         n_obs = len(pred_eng.predictor.req_mem_list)
         pred_eng.run(reqs())
@@ -170,7 +168,7 @@ class TestServeEngine:
         params, _ = registry.init_params(jax.random.PRNGKey(0), cfg)
         eng = ServeEngine(cfg, params,
                           EngineConfig(max_batch=1, max_context=96,
-                                       partition_gb=1e-4, predict=True))
+                                       partition_gb=1e-4))
         with pytest.raises(NeedsLargerPartition):
             eng.run([Request(uid=0, prompt=np.arange(4, dtype=np.int32),
                              max_new_tokens=80)])
