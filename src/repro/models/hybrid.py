@@ -109,45 +109,39 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     m = max(cfg.attn_every, 1)
     shared = params["shared_attn"]
 
-    def mamba_step(h, xs):
-        lp, conv_c, state_c = xs
-        out, conv_c, state_c = ssm_lib.ssm_decode_step(
-            lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), conv_c, state_c, cfg)
-        return h + out, (conv_c, state_c)
+    def mamba_step(carry, xs):
+        h, ssm_c = carry
+        lp, layer = xs
+        out, ssm_c = ssm_lib.ssm_decode_layer(
+            lp, rmsnorm(h, lp["norm1"], cfg.norm_eps), ssm_c, layer, cfg)
+        return (h + out, ssm_c), None
 
     grouped = jax.tree_util.tree_map(
         lambda a: a.reshape((n_groups, m) + a.shape[1:]),
         params["mamba_layers"])
-    conv_g = caches["ssm"]["conv"].reshape(
-        (n_groups, m) + caches["ssm"]["conv"].shape[1:])
-    state_g = caches["ssm"]["state"].reshape(
-        (n_groups, m) + caches["ssm"]["state"].shape[1:])
+    # group g's layer j is g * m + j of the flat [n_groups * m] ssm stacks
+    layers = jnp.arange(n_groups * m).reshape(n_groups, m)
 
-    def group_body(h, xs):
-        lp_group, conv_cg, state_cg, ck, cv = xs
-        h, (conv_cg, state_cg) = jax.lax.scan(
-            mamba_step, h, (lp_group, conv_cg, state_cg))
+    def group_body(carry, xs):
+        lp_group, layer_group, ck, cv = xs
+        (h, ssm_c), _ = jax.lax.scan(mamba_step, carry,
+                                     (lp_group, layer_group))
         out, k_new, v_new = attn.mha_decode_append(
             shared, rmsnorm(h, shared["norm1"], cfg.norm_eps), cfg, ck, cv,
             index)
         h = h + out
         h = h + mlp(shared, rmsnorm(h, shared["norm2"], cfg.norm_eps), cfg)
-        return h, (conv_cg, state_cg, k_new, v_new)
+        return (h, ssm_c), (k_new, v_new)
 
-    x, (conv_g, state_g, k_new, v_new) = jax.lax.scan(
-        group_body, x,
-        (grouped, conv_g, state_g, caches["attn_k"], caches["attn_v"]))
+    (x, ssm_c), (k_new, v_new) = jax.lax.scan(
+        group_body, (x, caches["ssm"]),
+        (grouped, layers, caches["attn_k"], caches["attn_v"]))
     new = attn.write_positions(caches, {"attn_k": k_new, "attn_v": v_new},
                                index)
-    new["ssm"] = {
-        "conv": conv_g.reshape((n_groups * m,) + conv_g.shape[2:]),
-        "state": state_g.reshape((n_groups * m,) + state_g.shape[2:]),
-    }
+    new["ssm"] = ssm_c
     if remainder:
-        x, (conv_t, state_t) = jax.lax.scan(
-            mamba_step, x,
-            (params["mamba_tail"], caches["ssm_tail"]["conv"],
-             caches["ssm_tail"]["state"]))
-        new["ssm_tail"] = {"conv": conv_t, "state": state_t}
+        (x, new["ssm_tail"]), _ = jax.lax.scan(
+            mamba_step, (x, caches["ssm_tail"]),
+            (params["mamba_tail"], jnp.arange(remainder)))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return unembed(params, x, cfg), new

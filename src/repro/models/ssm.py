@@ -215,3 +215,28 @@ def ssm_decode_step(params: dict, x: jax.Array, cache_conv, cache_state,
     y = rmsnorm(y, params["norm"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"])
     return constrain(out, ("batch", "seq", None)), cache_conv, state
+
+
+def ssm_decode_layer(params: dict, x: jax.Array, caches: dict,
+                     layer: jax.Array, cfg: ModelConfig
+                     ) -> tuple[jax.Array, dict]:
+    """:func:`ssm_decode_step` of layer ``layer`` (a scalar) on the stacked
+    caches ``{"conv": [L,B,W-1,ch], "state": [L,B,H,P,N]}``: reads the
+    layer's conv window and state, and writes the new ones back at
+    ``layer``.  Returns (y, the caches).
+
+    A layer scan carries the caches and calls this per layer, so that no
+    stack leaves the scan as its output: donated, they are written in
+    place, where a scan that returned every layer's state would restack it
+    and copy the stack into the donated buffer on every step."""
+    conv = jax.lax.dynamic_index_in_dim(caches["conv"], layer,
+                                        keepdims=False)
+    with jax.named_scope("repro.ssm.state"):
+        state = jax.lax.dynamic_index_in_dim(caches["state"], layer,
+                                             keepdims=False)
+    y, conv, state = ssm_decode_step(params, x, conv, state, cfg)
+    conv = jax.lax.dynamic_update_index_in_dim(caches["conv"], conv, layer, 0)
+    with jax.named_scope("repro.ssm.state"):
+        state = jax.lax.dynamic_update_index_in_dim(caches["state"], state,
+                                                    layer, 0)
+    return y, {"conv": conv, "state": state}

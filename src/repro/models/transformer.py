@@ -288,16 +288,16 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
 
     if cfg.family == "ssm":
         def body(carry, xs):
-            h = carry
-            lp, conv_c, state_c = xs
-            out, conv_c, state_c = ssm_lib.ssm_decode_step(
-                lp, _ssm_input(h, lp, cfg, dtype), conv_c, state_c, cfg)
-            return h + out, (conv_c, state_c)
+            h, ssm_c = carry
+            lp, layer = xs
+            out, ssm_c = ssm_lib.ssm_decode_layer(
+                lp, _ssm_input(h, lp, cfg, dtype), ssm_c, layer, cfg)
+            return (h + out, ssm_c), None
 
-        x, (conv_cs, state_cs) = jax.lax.scan(
-            body, _residual(x, cfg), (params["layers"], caches["ssm"]["conv"],
-                                      caches["ssm"]["state"]))
-        caches = {"ssm": {"conv": conv_cs, "state": state_cs}}
+        (x, ssm_c), _ = jax.lax.scan(
+            body, (_residual(x, cfg), caches["ssm"]),
+            (params["layers"], jnp.arange(cfg.n_layers)))
+        caches = {"ssm": ssm_c}
     elif cfg.n_experts and cfg.moe_every > 1:
         x, caches = _decode_interleaved_moe(params, cfg, x, index, caches,
                                             windows)

@@ -43,9 +43,11 @@ device down to the innermost span covering it):
 Device scopes (``jax.named_scope``: the HLO metadata of the ops inside,
 so the device trace's ops carry them):
 
-- ``repro.ssm.state`` (``models/ssm.ssm_decode_step``): the decode step's
-  recurrent-state update (decay, outer product, add), its read-out and the
-  ``D`` skip.  ``ssm.state_ms`` and ``ssm.state_roofline``.
+- ``repro.ssm.state`` (``models/ssm.ssm_decode_step`` and
+  ``ssm_decode_layer``): the decode step's recurrent-state update (decay,
+  outer product, add), its read-out and the ``D`` skip, and the read and
+  in-place write of the layer's state in the stacked cache.
+  ``ssm.state_ms`` and ``ssm.state_roofline``.
 
 Counters:
 

@@ -4,8 +4,9 @@ The TPU compiler refuses what interpret mode accepts: blocks off the (8, 128)
 tiling, ops Mosaic cannot lower, programs that overflow HBM.  These tests
 compile the Pallas kernels (``interpret=False``) at the widths of the models
 that use them, and the full-width qwen3-1.7b decode step and the engine's
-prefill of qwen3-1.7b and mamba2-2.7b, for a chip that is described and not
-attached: they must fit, and must write their donated caches in place.
+prefill of qwen3-1.7b and mamba2-2.7b, and the mamba2-2.7b decode step, for a
+chip that is described and not attached: they must fit, and must write
+their donated caches in place.
 Nothing runs, so they say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -127,6 +128,37 @@ def test_qwen3_decode_step_writes_cache_in_place(one_chip):
                      re.M)
     copies = [name for name, typ in ops
               if name.startswith("copy") and typ.startswith(stack)]
+    assert not copies, copies
+
+
+def test_mamba2_decode_step_writes_state_in_place(one_chip):
+    """At the chat cell's shape (16 rows, context 256) the compiled step
+    holds no temporary as large as one layer's state and copies neither
+    SSM stack: the layer scan writes each layer's conv window and state
+    into the donated stacks in place."""
+    cfg = get_config("mamba2-2.7b")
+    batch, context = 16, 256
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = on_chip(registry.abstract_params(cfg)[0])
+    caches = on_chip(jax.eval_shape(
+        lambda: registry.init_caches(cfg, batch, context)))
+    compiled = jax.jit(
+        lambda p, t, i, c: registry.decode_step(p, cfg, t, i, c),
+        donate_argnums=(3,)).lower(
+        params, _sds((batch, 1), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), caches).compile()
+    state = caches["ssm"]["state"]
+    layer_bytes = state.size // cfg.n_layers * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    stacks = ["f32[{}]".format(",".join(map(str, a.shape)))
+              for a in (state, caches["ssm"]["conv"])]
+    ops = re.findall(r"^\s*(?:ROOT )?%(\S+) = (\S+)", compiled.as_text(),
+                     re.M)
+    copies = [name for name, typ in ops if name.startswith("copy")
+              and typ.split("{")[0] in stacks]
     assert not copies, copies
 
 

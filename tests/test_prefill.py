@@ -190,15 +190,17 @@ def test_prompts_of_one_bucket_share_a_prefill_program():
 
 
 #: sha256 of the engine's decode step for the cells' shapes, lowered
-#: (``Lowered.as_text()``, JAX 0.9.0) before the prefill was added: the
-#: decode program the cells run does not change with it
+#: (``Lowered.as_text()``, JAX 0.9.0): qwen3's before the prefill was
+#: added, which left the decode program unchanged; mamba2's since its layer
+#: scan carries the SSM stacks and writes each layer's state in place
+#: (``ssm.ssm_decode_layer``) instead of returning them restacked
 DECODE_STEP_SHA256 = {
     ("qwen3-1.7b", 32, 512):
         "28c2f10cc932101902f51318279569f8557f43e8d5b74341cc3348b197578c56",
     ("qwen3-1.7b", 64, 512):
         "9149519e5be7373adc08cc4c5015990657f9f5302dae70a06092a9871360add5",
     ("mamba2-2.7b", 16, 256):
-        "a6865a7e6322a114aedef89e70369807979cb6a02c56aa59c34ea9a37967130c",
+        "d4ff425a32fe4df108f51b00c4724202fd487732255ee4f81331137127cf20d8",
 }
 
 
